@@ -1,12 +1,15 @@
-"""Distributed CP-ALS sweep scaling on 1/2/4/8 fake host devices.
+"""Distributed CP-ALS sweep scaling on 1/2/4/8 devices.
 
-Each device count runs in a fresh subprocess because
-``--xla_force_host_platform_device_count`` must be set before the first
-jax import. Rows: ``dist_cpals/<tensor>/dev<N>`` — one full sharded
-CP-ALS sweep (sharded MTTKRP all modes + psum'd Grams) per call. On the
-CPU host the fake devices timeshare one core, so this measures collective
-+ partitioning overhead, not speedup — the scaling *shape* (flat ≈ free
-sharding) is the signal; real speedups need one chip per shard.
+Rows: ``dist_cpals/<tensor>/dev<N>`` — one full sharded CP-ALS sweep
+(sharded MTTKRP all modes + psum'd Grams) per call.
+
+On a TPU host every device count runs in this process over the first N
+chips (a chip belongs to one process, so a child could not reach it).
+Off the chip each device count runs in a fresh CPU-only subprocess,
+because ``--xla_force_host_platform_device_count`` must be set before
+the first jax import; the fake devices timeshare the host's cores, so
+that measures collective + partitioning overhead, not speedup — the
+scaling *shape* (flat ≈ free sharding) is the signal.
 """
 from __future__ import annotations
 
@@ -18,8 +21,16 @@ DEVICE_COUNTS = (1, 2, 4, 8)
 
 
 def run(quick: bool = False) -> None:
+    import jax
+    if jax.default_backend() == "tpu":
+        devices = jax.devices()
+        for n in DEVICE_COUNTS:
+            if n <= len(devices):
+                _worker(n, quick, devices=devices[:n])
+        return
     for n in DEVICE_COUNTS:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         env.setdefault("PYTHONPATH", "src:.")
         cmd = [sys.executable, "-m", "benchmarks.bench_dist",
@@ -32,7 +43,7 @@ def run(quick: bool = False) -> None:
             raise RuntimeError(f"dev{n} worker failed:\n{r.stderr[-2000:]}")
 
 
-def _worker(n_dev: int, quick: bool) -> None:
+def _worker(n_dev: int, quick: bool, devices=None) -> None:
     import functools
 
     import jax
@@ -43,7 +54,7 @@ def _worker(n_dev: int, quick: bool) -> None:
     from repro.dist import cpd
     from repro.sparse import synthetic
 
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = jax.make_mesh((n_dev,), ("data",), devices=devices)
     rank = 8
     dims, nnz = ((1024, 256, 128), 30_000) if quick else \
         ((4096, 1024, 256), 120_000)

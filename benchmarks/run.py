@@ -16,6 +16,8 @@ def main() -> None:
                          "outofcore,incremental")
     args = ap.parse_args()
 
+    from repro import caches
+    caches.use_compile_cache()
     from benchmarks import (bench_autotune, bench_cpapr, bench_dist,
                             bench_format_generation, bench_incremental,
                             bench_kernels, bench_mttkrp,

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 import threading
 from typing import Sequence
 
@@ -286,101 +285,121 @@ def device_ingest_traces() -> dict[str, int]:
         return dict(_DEVICE_INGEST_TRACES)
 
 
-def _build_device_fn(enc: AltoEncoding, L: int, M: int,
-                     compute_reuse: bool, val_dtype):
+def _build_device_fn(enc: AltoEncoding, L: int, M: int, val_dtype):
     """The cached jitted device-build core for one static meta."""
-    key = ("build", enc, L, M, bool(compute_reuse),
-           jnp.dtype(val_dtype).name)
+    key = ("build", enc, L, M, jnp.dtype(val_dtype).name)
     N, W = enc.ndim, enc.n_words
     chunk = -(-max(M, L) // L)
     Mp = chunk * L
-    # Host-precomputed complement masks: which index bits do NOT belong
-    # to each mode (fiber counting masks the mode out of the key).
-    not_masks = ~enc.mode_masks()                        # (N, W) u32
 
     def core(coords, values):
         _DEVICE_INGEST_TRACES["build"] += 1              # trace-time only
-        words = linearize(enc, coords)                   # (M, W) u32
-        ccols = [coords[:, n].astype(jnp.int32) for n in range(N)]
-        words, values, *ccols = enc_mod.sort_by_key(words, values, *ccols)
+        words, values = enc_mod.sort_by_key(linearize(enc, coords), values)
         if Mp > M:
             # Same padding rule as build(): value-0 copies of the last
             # element so the tail stays inside the final bounding box.
             pad = Mp - M
-            if M == 0:
-                pw = jnp.zeros((pad, W), jnp.uint32)
-                pc = [jnp.zeros((pad,), jnp.int32)] * N
-            else:
-                pw = jnp.broadcast_to(words[-1:], (pad, W))
-                pc = [jnp.broadcast_to(c[-1:], (pad,)) for c in ccols]
+            pw = (jnp.zeros((pad, W), jnp.uint32) if M == 0
+                  else jnp.broadcast_to(words[-1:], (pad, W)))
             words = jnp.concatenate([words, pw])
             values = jnp.concatenate(
                 [values, jnp.zeros((pad,), values.dtype)])
-            ccols = [jnp.concatenate([c, p]) for c, p in zip(ccols, pc)]
-        cc = jnp.stack(ccols, axis=-1).reshape(L, chunk, N)
+        # delinearize is linearize's exact inverse: these are the input
+        # coordinates in stream order, without sorting them along.
+        cc = delinearize(enc, words).reshape(L, chunk, N)
         part_start = jnp.min(cc, axis=1).astype(jnp.int32)
         part_end = jnp.max(cc, axis=1).astype(jnp.int32)
-        if compute_reuse and M > 0:
-            fibers = jnp.stack([
-                enc_mod.count_distinct(
-                    words[:M] & jnp.asarray(not_masks[n])[None, :])
-                for n in range(N)])
-        else:
-            fibers = jnp.ones((N,), jnp.int32)
-        return words, values, part_start, part_end, fibers
+        return words, values, part_start, part_end
 
     return _cached_ingest_fn(key, lambda: jax.jit(core))
+
+
+def _fiber_count_fn(M: int, Mp: int, W: int):
+    """The cached jitted fiber counter: distinct keys of ``words[:M]``
+    with one mode's index bits masked out. The mask is an operand, so
+    one compiled program serves every mode."""
+    def core(words, not_mask):
+        return enc_mod.count_distinct(words[:M] & not_mask[None, :])
+
+    return _cached_ingest_fn(("fibers", M, Mp, W), lambda: jax.jit(core))
+
+
+def fiber_reuse_device(enc: AltoEncoding, words: jnp.ndarray,
+                       nnz: int) -> tuple[float, ...]:
+    """`fiber_reuse_stats` on device: average nonzeros per fiber along
+    each mode, from the sorted (padded) stream's first ``nnz`` words."""
+    if nnz == 0:
+        return tuple(0.0 for _ in range(enc.ndim))
+    fn = _fiber_count_fn(nnz, words.shape[0], enc.n_words)
+    not_masks = ~enc.mode_masks()                        # (N, W) u32
+    return tuple(float(nnz) / max(1, int(fn(words, jnp.asarray(m))))
+                 for m in not_masks)
+
+
+def finalize_device(enc: AltoEncoding, nnz: int, L: int, words, values,
+                    part_start, part_end,
+                    compute_reuse: bool) -> AltoTensor:
+    """The `AltoTensor` of a device-built stream. The only host transfer
+    is the (L, N) bounding boxes and N fiber counts, O(L·N) scalars;
+    the O(nnz) stream never leaves the device."""
+    ps = np.asarray(part_start)
+    pe = np.asarray(part_end)
+    temp_rows = tuple(int((pe[:, n] - ps[:, n]).max()) + 1
+                      for n in range(enc.ndim))
+    reuse = (fiber_reuse_device(enc, words, nnz) if compute_reuse
+             else tuple(float("nan") for _ in range(enc.ndim)))
+    meta = AltoMeta(enc=enc, nnz=nnz, n_partitions=L, temp_rows=temp_rows,
+                    fiber_reuse=reuse)
+    return AltoTensor(meta=meta, words=words, values=values,
+                      part_start=part_start, part_end=part_end)
 
 
 def build_device(x: SparseTensor, n_partitions: int = 8,
                  compute_reuse: bool = True) -> AltoTensor:
     """ALTO format generation on device — `build`'s jittable twin.
 
-    linearize (jnp bit gather) → ONE stable multi-word key sort carrying
-    values + coordinate columns (`encoding.sort_by_key`) → reshaped
-    min/max partition bounding boxes, all inside a single jitted core
-    with zero host callbacks, traced once per (encoding, L, nnz, dtype).
+    linearize (jnp bit gather) → ONE stable multi-word key sort
+    (`encoding.sort_by_key`) → reshaped min/max partition bounding
+    boxes, all inside a single jitted core with zero host callbacks,
+    traced once per (encoding, L, nnz, dtype); fiber counts follow as
+    one shared jitted counter per mode (`fiber_reuse_device`).
     Bit-identical to `build` — same element order (stable sort, so
     duplicate linearized keys keep COO input order), same padding, same
-    static meta (the (L, N) bounding boxes and N fiber counts are the
-    only host transfer, to finalize the hashable `AltoMeta`).
+    static meta.
     """
     enc = make_encoding(x.dims)
     L = max(1, int(n_partitions))
     M = x.nnz
     coords = jnp.asarray(x.coords)
     values = jnp.asarray(x.values)
-    fn = _build_device_fn(enc, L, M, compute_reuse, values.dtype)
-    words, vals, part_start, part_end, fibers = fn(coords, values)
-    ps = np.asarray(part_start)                          # (L, N): tiny
-    pe = np.asarray(part_end)
-    temp_rows = tuple(int((pe[:, n] - ps[:, n]).max()) + 1
-                      for n in range(enc.ndim))
-    if compute_reuse:
-        reuse = tuple(float(M) / max(1, int(f)) for f in np.asarray(fibers))
-    else:
-        reuse = tuple(float("nan") for _ in range(enc.ndim))
-    meta = AltoMeta(enc=enc, nnz=M, n_partitions=L, temp_rows=temp_rows,
-                    fiber_reuse=reuse)
-    return AltoTensor(meta=meta, words=words, values=vals,
-                      part_start=part_start, part_end=part_end)
+    fn = _build_device_fn(enc, L, M, values.dtype)
+    return finalize_device(enc, M, L, *fn(coords, values),
+                           compute_reuse=compute_reuse)
 
 
-def _view_device_fn(enc: AltoEncoding, mode: int, Mp: int, val_dtype):
-    """The cached jitted oriented-view core for one static meta/mode."""
-    key = ("view", enc, mode, Mp, jnp.dtype(val_dtype).name)
-    W = enc.n_words
-
-    def core(words, values):
+def _view_rows_fn(enc: AltoEncoding, mode: int, Mp: int):
+    """The cached jitted target-row extraction for one static meta/mode."""
+    def core(words):
         _DEVICE_INGEST_TRACES["view"] += 1               # trace-time only
-        rows = enc_mod.extract_mode(enc, words, mode)    # (Mp,) int32
-        perm0 = jnp.arange(Mp, dtype=jnp.int32)
-        cols = [words[:, w] for w in range(W)]
-        res = jax.lax.sort((rows, *cols, values, perm0), num_keys=1,
-                           is_stable=True)
-        return (res[0], jnp.stack(res[1:1 + W], axis=-1), res[1 + W],
-                res[2 + W])
+        return enc_mod.extract_mode(enc, words, mode)    # (Mp,) int32
 
+    return _cached_ingest_fn(("view", enc, mode, Mp), lambda: jax.jit(core))
+
+
+def _view_sort_fn(Mp: int, W: int, val_dtype):
+    """The cached jitted row sort, shared by every mode of a stream.
+
+    Only the rows and an iota enter the stable sort (every operand a TPU
+    sort carries adds to its compile time); the permutation then gathers
+    the words and values."""
+    def core(rows, words, values):
+        perm0 = jnp.arange(Mp, dtype=jnp.int32)
+        rows, perm = jax.lax.sort((rows, perm0), num_keys=1,
+                                  is_stable=True)
+        words = jnp.stack([words[:, w][perm] for w in range(W)], axis=-1)
+        return rows, words, values[perm], perm
+
+    key = ("view_sort", Mp, W, jnp.dtype(val_dtype).name)
     return _cached_ingest_fn(key, lambda: jax.jit(core))
 
 
@@ -389,15 +408,17 @@ def oriented_view_device(at: AltoTensor, mode: int) -> OrientedView:
 
     Target-mode rows come from a masked bit-extract of the words
     (`encoding.extract_mode` — no full delinearize), then ONE stable
-    `lax.sort` by row carries the words, values, and the Π permutation
-    (an iota, which IS the stable argsort). Stability keeps ALTO order
-    within each row — bit-identical to the host `oriented_view`,
-    duplicate-coordinate ties included. Jit-compatible, zero host
-    callbacks, traced once per (encoding, mode, Mp, dtype).
+    `lax.sort` of the rows and an iota yields the Π permutation (the
+    stable argsort), which gathers the words and values. Stability keeps
+    ALTO order within each row — bit-identical to the host
+    `oriented_view`, duplicate-coordinate ties included. Jit-compatible,
+    zero host callbacks; the extraction is traced once per (encoding,
+    mode, Mp), the sort once per (Mp, n_words, dtype).
     """
-    fn = _view_device_fn(at.meta.enc, mode, at.words.shape[0],
-                         at.values.dtype)
-    rows, words, values, perm = fn(at.words, at.values)
+    Mp, W = at.words.shape
+    rows = _view_rows_fn(at.meta.enc, mode, Mp)(at.words)
+    rows, words, values, perm = _view_sort_fn(Mp, W, at.values.dtype)(
+        rows, at.words, at.values)
     return OrientedView(meta=at.meta, mode=mode, rows=rows, words=words,
                         values=values, perm=perm)
 

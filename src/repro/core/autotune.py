@@ -23,7 +23,8 @@ the plan stack:
   ranking (documented in docs/known-issues.md); on TPU the same protocol
   times real Mosaic executables.
 * **plan store** — winners persist in a versioned JSON file
-  (``$REPRO_PLAN_CACHE`` or ``~/.cache/repro/plans.json``), keyed on a
+  (``$REPRO_PLAN_CACHE`` or the checkout's ``.cache/plans.json``,
+  `repro.caches`), keyed on a
   stable hash of (meta fingerprint, rank, backend, device platform,
   shard count, dtype/vmem budget, jax version, store version). A second
   process calling ``make_plan(..., tune="auto"|"force")`` gets the
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import caches
 from repro.core import faults
 from repro.core import heuristics
 from repro.core import mttkrp as core_mttkrp
@@ -64,7 +66,7 @@ from repro.core.alto import AltoMeta, AltoTensor, delinearize
 # v2 stores load as empty — never clobbered until the first new write.
 PLAN_STORE_VERSION = 3
 PLAN_CACHE_ENV = "REPRO_PLAN_CACHE"
-DEFAULT_STORE = "~/.cache/repro/plans.json"
+DEFAULT_STORE = str(caches.CACHE_DIR / "plans.json")
 
 DEFAULT_WARMUP = 1
 DEFAULT_ITERS = 3
@@ -150,7 +152,7 @@ def class_plan_key(sc, backend: str, **kwargs) -> str:
 
 def store_path(override=None) -> pathlib.Path:
     """Resolve the plan-store file: explicit arg > $REPRO_PLAN_CACHE >
-    ~/.cache/repro/plans.json."""
+    the checkout's .cache/plans.json."""
     if override is not None:
         return pathlib.Path(override).expanduser()
     env = os.environ.get(PLAN_CACHE_ENV)

@@ -304,50 +304,61 @@ def pack_key(words: jnp.ndarray):
     return None
 
 
+def _stable_order(cols) -> jnp.ndarray:
+    """Stable argsort of the multi-word key whose u32 words are ``cols``
+    (LSW first): the permutation :func:`sort_by_key` applies.
+
+    Only the key words and an iota enter the sort — every sorted operand
+    costs the TPU sort's compile time, while a gather by the permutation
+    is cheap. ≤2 words sort ONCE (a packed u64 key where 64-bit lanes
+    exist, else a two-key lexicographic sort, MSW primary — same order);
+    wider keys take LSW→MSW stable passes (word-wise LSD radix: each pass
+    stably sorts the permutation by one word, so the composition orders by
+    the most-significant word with ties resolved by lower words, exactly
+    `sort_key_np`'s ``np.lexsort``). The iota makes every pass stable.
+    """
+    M = cols[0].shape[0]
+    iota = jax.lax.iota(jnp.int32, M)
+    key = pack_key(jnp.stack(cols, axis=-1)) if len(cols) <= 2 else None
+    if key is not None:
+        return jax.lax.sort((key, iota), num_keys=1, is_stable=True)[1]
+    if len(cols) == 2:
+        return jax.lax.sort((cols[1], cols[0], iota), num_keys=2,
+                            is_stable=True)[2]
+    perm = iota
+    for c in cols:                               # LSW -> MSW stable passes
+        perm = jax.lax.sort((c[perm], perm), num_keys=1, is_stable=True)[1]
+    return perm
+
+
 def sort_by_key(words: jnp.ndarray, *operands: jnp.ndarray):
     """Stable ascending device sort by the multi-word ALTO key.
 
     ``words`` is (M, W) u32; ``operands`` are (M,) arrays carried through
-    the same permutation (values, coordinate columns, iota for an
-    argsort). Returns ``(sorted_words, *sorted_operands)``.
-
-    Strategy by width: ≤2 words sort ONCE on the packed key
-    (:func:`pack_key`; without x64 two words become one two-key
-    lexicographic `lax.sort`, MSW primary — same order, no 64-bit
-    lanes); beyond that, LSW→MSW stable passes (word-wise LSD radix —
-    each pass is a stable single-key sort, so the composition orders by
-    the most-significant word with ties resolved by lower words, exactly
-    `sort_key_np`'s ``np.lexsort``). Every path is stable, so duplicate
-    full keys keep their input order — the tie rule the oriented-view
-    and build parity contracts depend on.
+    the same permutation (the values). Returns
+    ``(sorted_words, *sorted_operands)``. The sort is stable, so
+    duplicate full keys keep their input order — the tie rule the
+    oriented-view and build parity contracts depend on.
     """
-    M, W = words.shape
-    cols = [words[:, w] for w in range(W)]
-    ops = list(operands)
-    key = pack_key(words)
-    if key is not None:
-        res = jax.lax.sort((key, *cols, *ops), num_keys=1, is_stable=True)
-        srt = list(res[1:])
-    elif W == 2:
-        res = jax.lax.sort((cols[1], cols[0], *ops), num_keys=2,
-                           is_stable=True)
-        srt = [res[1], res[0], *res[2:]]
-    else:
-        srt = cols + ops
-        for w in range(W):                      # LSW -> MSW stable passes
-            rest = srt[:w] + srt[w + 1:]
-            res = jax.lax.sort((srt[w], *rest), num_keys=1, is_stable=True)
-            srt = list(res[1:w + 1]) + [res[0]] + list(res[w + 1:])
-    return (jnp.stack(srt[:W], axis=-1), *srt[W:])
+    cols = [words[:, w] for w in range(words.shape[1])]
+    perm = _stable_order(cols)
+    return (jnp.stack([c[perm] for c in cols], axis=-1),
+            *[op[perm] for op in operands])
 
 
 def count_distinct(words: jnp.ndarray) -> jnp.ndarray:
     """Distinct rows of an (M, W) u32 array, on device (sort + adjacent
-    diff — the jittable sibling of :func:`count_distinct_np`)."""
+    diff — the jittable sibling of :func:`count_distinct_np`). Counting
+    needs only the order, so the sort is an unstable lexicographic sort
+    of the key words alone."""
     if words.shape[0] == 0:
         return jnp.asarray(0, jnp.int32)
-    srt = sort_by_key(words)[0]
-    neq = jnp.any(srt[1:] != srt[:-1], axis=-1)
+    W = words.shape[1]
+    srt = jax.lax.sort(tuple(words[:, w] for w in reversed(range(W))),
+                       num_keys=W, is_stable=False)
+    neq = jnp.zeros((words.shape[0] - 1,), jnp.bool_)
+    for c in srt:
+        neq = neq | (c[1:] != c[:-1])
     return jnp.asarray(1, jnp.int32) + jnp.sum(neq, dtype=jnp.int32)
 
 

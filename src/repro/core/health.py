@@ -31,8 +31,9 @@ finite, degraded answer, not a stack trace.
 
 :func:`degrade_plan` is the plan half of the recovery ladders: given a
 plan and the exception it produced, return the next-softer plan (halve
-``chunk_m`` on streaming OOM, drop Pallas to the reference backend on a
-kernel/dispatch failure) or None when out of rungs.
+``chunk_m`` on streaming OOM) or None when out of rungs. A kernel
+failure has no softer plan: it is a defect to surface, not a fault to
+heal.
 """
 from __future__ import annotations
 
@@ -119,17 +120,16 @@ def tenants_finite(arrays) -> np.ndarray:
 def degrade_plan(plan: plan_mod.ExecutionPlan, exc: BaseException):
     """Next-softer plan after ``plan`` failed with ``exc``, or (None, None).
 
-    Rungs, in order:
+    One rung: streaming OOM → halve ``chunk_m`` (kept a multiple of the
+    plan's largest block_m so chunk-parity alignment survives) and
+    re-count chunks. Repeatable until one aligned chunk remains.
 
-    1. streaming OOM → halve ``chunk_m`` (kept a multiple of the plan's
-       largest block_m so chunk-parity alignment survives) and re-count
-       chunks. Repeatable until one aligned chunk remains.
-    2. Pallas kernel/dispatch failure → same routing on the reference
-       (pure-jnp) backend. The reference path is tolerance-level against
-       Pallas, so the degraded answer is still a real answer.
-
-    Transient faults (I/O, allocator blips — `faults.is_transient`)
-    should be *retried*, not degraded; callers check that first.
+    A kernel that fails to build or dispatch gets no rung: swapping the
+    Pallas backend for the jnp reference would serve a chip defect as a
+    "degraded" answer, so the failure surfaces to the caller (a served
+    request gets it as its structured error). Transient faults (I/O,
+    allocator blips — `faults.is_transient`) should be *retried*, not
+    degraded; callers check that first.
     """
     msg = str(exc)
     if plan.streaming is not None and "RESOURCE_EXHAUSTED" in msg:
@@ -142,8 +142,4 @@ def degrade_plan(plan: plan_mod.ExecutionPlan, exc: BaseException):
                 n_chunks=plan_mod.chunk_count(plan.meta, new_cm))
             return (dataclasses.replace(plan, streaming=streaming),
                     f"halved chunk_m {cm} -> {new_cm}")
-        # out of chunk headroom: fall through to the backend rung
-    if plan.backend == "pallas":
-        return (dataclasses.replace(plan, backend="reference"),
-                "pallas -> reference backend")
     return None, None

@@ -54,7 +54,7 @@ from repro.core import alto
 from repro.core import encoding as enc_mod
 from repro.core import faults
 from repro.core import views as views_mod
-from repro.core.alto import AltoMeta, AltoTensor
+from repro.core.alto import AltoTensor
 from repro.core.encoding import AltoEncoding, make_encoding
 
 POLICIES = alto.MERGE_POLICIES
@@ -66,7 +66,7 @@ POLICIES = alto.MERGE_POLICIES
 
 def _merge_device_fn(old_enc: AltoEncoding, new_enc: AltoEncoding, L: int,
                      M: int, res_len: int, D: int, policy: str,
-                     compute_reuse: bool, val_dtype, delta_form: str):
+                     val_dtype, delta_form: str):
     """The cached jitted delta-merge core for one static merge meta.
 
     ``delta_form`` is "coords" ((D, N) int32, linearized in-jit — the
@@ -76,12 +76,11 @@ def _merge_device_fn(old_enc: AltoEncoding, new_enc: AltoEncoding, L: int,
     lengths so the trace-once contract keys on the full static shape.
     """
     key = ("merge", old_enc, new_enc, L, M, res_len, D, policy,
-           bool(compute_reuse), jnp.dtype(val_dtype).name, delta_form)
+           jnp.dtype(val_dtype).name, delta_form)
     N, W = new_enc.ndim, new_enc.n_words
     MD = M + D
     chunk = -(-max(MD, L) // L)
     Mp = chunk * L
-    not_masks = ~new_enc.mode_masks()                    # (N, W) u32
 
     def core(res_words, res_values, delta, delta_values):
         alto._DEVICE_INGEST_TRACES["merge"] += 1         # trace-time only
@@ -116,36 +115,9 @@ def _merge_device_fn(old_enc: AltoEncoding, new_enc: AltoEncoding, L: int,
         cc = alto.delinearize(new_enc, words).reshape(L, chunk, N)
         part_start = jnp.min(cc, axis=1).astype(jnp.int32)
         part_end = jnp.max(cc, axis=1).astype(jnp.int32)
-        if compute_reuse and MD > 0:
-            fibers = jnp.stack([
-                enc_mod.count_distinct(
-                    words[:MD] & jnp.asarray(not_masks[n])[None, :])
-                for n in range(N)])
-        else:
-            fibers = jnp.ones((N,), jnp.int32)
-        return words, values, part_start, part_end, fibers
+        return words, values, part_start, part_end
 
     return alto._cached_ingest_fn(key, lambda: jax.jit(core))
-
-
-def _finalize(fn_out, new_enc: AltoEncoding, MD: int, L: int,
-              compute_reuse: bool) -> AltoTensor:
-    """Host meta finalization — same tiny transfer as `build_device`:
-    the (L, N) boxes and N fiber counts, never the O(nnz) stream."""
-    words, vals, part_start, part_end, fibers = fn_out
-    ps = np.asarray(part_start)
-    pe = np.asarray(part_end)
-    temp_rows = tuple(int((pe[:, n] - ps[:, n]).max()) + 1
-                      for n in range(new_enc.ndim))
-    if compute_reuse:
-        reuse = tuple(float(MD) / max(1, int(f))
-                      for f in np.asarray(fibers))
-    else:
-        reuse = tuple(float("nan") for _ in range(new_enc.ndim))
-    meta = AltoMeta(enc=new_enc, nnz=MD, n_partitions=L,
-                    temp_rows=temp_rows, fiber_reuse=reuse)
-    return AltoTensor(meta=meta, words=words, values=vals,
-                      part_start=part_start, part_end=part_end)
 
 
 def _append(at: AltoTensor, delta, delta_values, new_dims: tuple[int, ...],
@@ -163,14 +135,14 @@ def _append(at: AltoTensor, delta, delta_values, new_dims: tuple[int, ...],
     M = at.meta.nnz
     D = int(delta.shape[0])
     fn = _merge_device_fn(old_enc, new_enc, L, M, int(at.words.shape[0]),
-                          D, policy, bool(compute_reuse), at.values.dtype,
-                          delta_form)
+                          D, policy, at.values.dtype, delta_form)
     # Interruption site: the merge is functional (the resident tensor is
     # never mutated), so a kill here leaves `at` fully serviceable and a
     # retry re-runs the identical jitted program.
     faults.inject("ingest.merge")
     out = fn(at.words, at.values, delta, delta_values)
-    new_at = _finalize(out, new_enc, M + D, L, bool(compute_reuse))
+    new_at = alto.finalize_device(new_enc, M + D, L, *out,
+                                  compute_reuse=bool(compute_reuse))
     if invalidate_stale:
         # Surgical: only modes whose content fingerprint moved lose their
         # cached views — a no-op append (empty delta, "sum") drops

@@ -34,11 +34,9 @@ The plan answers four questions the call sites used to guess at:
     plans force the output-oriented family for every mode (either
     variant — one-hot merge or shard-local scratch carry; row-range
     partitioning needs the row-sorted stream; the recursive traversal's
-    partition intervals overlap arbitrarily across devices) and divide the
-    VMEM budget by the shard count — shard-local blocks are sized as if
-    all shards ran concurrently on one core, which is exactly what the
-    fake-host-device test configuration does, and on real multi-chip
-    meshes it only makes tiles conservatively smaller.
+    partition intervals overlap arbitrarily across devices). Every
+    device of a mesh has its own VMEM, so shard-local tiles get the
+    whole per-core budget.
 
 Because `ExecutionPlan` is hashable (``jax.sharding.Mesh`` included) it can
 travel as a static jit argument and doubles as the key of the
@@ -72,15 +70,21 @@ from repro.core import heuristics
 from repro.core import mttkrp as core_mttkrp
 from repro.core.alto import AltoMeta, AltoTensor, OrientedView, delinearize
 
-# Per-core VMEM on current TPU generations; the budget is what the kernel's
-# per-grid-step working set must fit into (interpret mode ignores it but we
-# size identically so CPU tests exercise the TPU tiling decisions).
-VMEM_BYTES = 16 * 1024 * 1024
+# VMEM the kernels' blocks may use per grid step. A v5e core has 128 MiB
+# of VMEM; the kernels raise Mosaic's scoped limit to
+# `kernels.mttkrp.VMEM_LIMIT_BYTES` and the plan budgets below it, leaving
+# room for Mosaic's own temporaries. Interpret mode ignores VMEM, but the
+# plan sizes identically so the CPU tests exercise the chip's tilings.
+VMEM_BYTES = 96 * 1024 * 1024
 
-# Output-oriented kernel: the in-block one-hot segment matmul is
-# (block_m, block_m), so block_m is capped independently of the budget.
-MAX_BLOCK_M = 1024
-MIN_BLOCK_M = 8
+# Nonzero blocks are 1-D tiles of the lane-dense stream arrays, which XLA
+# lays out in 1024-element tiles on the chip: block_m is a multiple of
+# 1024. The one-hot kernel's (block_m, block_m) operand caps it above.
+MIN_BLOCK_M = 1024
+MAX_BLOCK_M = 2048
+# A rank tile is the whole rank or a multiple of the 128-lane vreg width.
+LANES = 128
+SUBLANE_BYTES = 32          # one (8, 128) f32 tile row group: 8 rows x 4 B
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,8 +92,8 @@ class ModePlan:
     """Resolved execution choices for one target mode."""
     mode: int
     traversal: heuristics.Traversal
-    r_block: int        # rank tile (always divides the plan rank)
-    block_m: int        # oriented-kernel nonzero block (power of two)
+    r_block: int        # rank tile: the rank, or a 128-multiple dividing it
+    block_m: int        # nonzero block (a multiple of MIN_BLOCK_M)
     temp_rows: int      # recursive Temp height (static VMEM bound)
     vmem_bytes: int     # estimated per-grid-step footprint (MTTKRP kernel)
     phi_vmem_bytes: int = 0   # fused Φ kernel footprint (full rank, B resident)
@@ -152,71 +156,91 @@ class ExecutionPlan:
 # VMEM budgeting
 # ---------------------------------------------------------------------------
 
-def _chunk_rows(meta: AltoMeta) -> int:
-    """Per-partition element count after build()'s padding to L·chunk."""
-    L = meta.n_partitions
-    return -(-max(meta.nnz, L) // L)
+def _tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """VMEM bytes of a (rows, cols) array in (8·4/itemsize, 128) tiles —
+    what Mosaic allocates, not rows·cols·itemsize."""
+    sub = SUBLANE_BYTES // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // LANES) * LANES) * itemsize
+
+
+def _stream_bytes(meta: AltoMeta, block_m: int, n_modes: int) -> int:
+    """Index-word blocks (lane-dense, double-buffered) plus the VMEM
+    staging tile of ``n_modes`` decoded coordinate rows."""
+    return (2 * meta.enc.n_words * block_m * 4
+            + _tile_bytes(n_modes, block_m))
+
+
+def _factor_bytes(meta: AltoMeta, mode: int, cols: int,
+                  dtype_bytes: int) -> int:
+    """Every other mode's resident (single-buffered) factor tile."""
+    return sum(_tile_bytes(I, cols, dtype_bytes)
+               for m, I in enumerate(meta.dims) if m != mode)
+
+
+def _onehot_bytes(block_m: int, dtype_bytes: int) -> int:
+    """The (block_m, block_m) one-hot operand and the iota it compares."""
+    return block_m * block_m * (4 + dtype_bytes)
+
 
 def recursive_vmem_bytes(meta: AltoMeta, mode: int, r_block: int,
-                         dtype_bytes: int = 4) -> int:
-    """Per-grid-step VMEM of the recursive (Temp + one-hot) kernel.
+                         dtype_bytes: int = 4,
+                         block_m: int = MIN_BLOCK_M) -> int:
+    """Per-grid-step VMEM of the recursive (partition Temp) kernel.
 
-    words + values tiles, the (chunk, T) one-hot operand, the (chunk, rb)
-    Khatri-Rao/contribution tile, the (T, rb) Temp output, and the resident
-    factor tiles of the other modes.
+    Word blocks plus the staging tile of all N coordinates, the
+    double-buffered (T, r_block) Temp output block, and the resident
+    factor tiles of the other modes. Values and staged coordinates live
+    in SMEM and are not counted.
     """
-    chunk = _chunk_rows(meta)
     T = meta.temp_rows[mode]
-    W = meta.enc.n_words
-    words = chunk * W * 4
-    values = chunk * dtype_bytes
-    onehot = chunk * T * dtype_bytes
-    contrib = chunk * r_block * dtype_bytes
-    temp = T * r_block * dtype_bytes
-    factors = sum(I for m, I in enumerate(meta.dims)
-                  if m != mode) * r_block * dtype_bytes
-    return words + values + onehot + contrib + temp + factors
+    return (_stream_bytes(meta, block_m, meta.enc.ndim)
+            + 2 * _tile_bytes(T, r_block, dtype_bytes)
+            + _factor_bytes(meta, mode, r_block, dtype_bytes))
 
 
 def oriented_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
                         r_block: int, dtype_bytes: int = 4) -> int:
-    """Per-grid-step VMEM of the output-oriented segment kernel.
+    """Per-grid-step VMEM of the output-oriented one-hot segment kernel.
 
-    Dominated by the (block_m, block_m) in-block segment one-hot; plus the
-    sorted rows / words / values tiles, the contribution tile, the
-    per-block segment-sum output, and the resident factor tiles.
+    Dominated by the (block_m, block_m) one-hot; plus the word blocks and
+    coordinate staging, the double-buffered segment-id block, the
+    contribution tile, the double-buffered segment-sum output block, and
+    the resident factor tiles.
     """
-    W = meta.enc.n_words
-    words = block_m * W * 4
-    rows = block_m * 4
-    values = block_m * dtype_bytes
-    onehot = block_m * block_m * dtype_bytes
-    contrib = 2 * block_m * r_block * dtype_bytes   # krp + segment sums
-    factors = sum(I for m, I in enumerate(meta.dims)
-                  if m != mode) * r_block * dtype_bytes
-    return words + rows + values + onehot + contrib + factors
+    return (_stream_bytes(meta, block_m, meta.enc.ndim - 1)
+            + 2 * block_m * 4
+            + _tile_bytes(block_m, r_block, dtype_bytes)
+            + _onehot_bytes(block_m, dtype_bytes)
+            + 2 * _tile_bytes(block_m, r_block, dtype_bytes)
+            + _factor_bytes(meta, mode, r_block, dtype_bytes))
 
 
 def oriented_carry_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
                               r_block: int, dtype_bytes: int = 4) -> int:
     """Per-grid-step VMEM of the scratch-carry oriented kernel.
 
-    No (block_m, block_m) one-hot — in-block segment sums are a VPU
-    scatter — but the ``(I_mode, r_block)`` output tile stays resident
-    across the whole sequential scan, plus the (1, r_block) carry
-    scratch. Stream tiles, krp/contrib/segment-sum intermediates, and
-    the resident factor tiles as in the one-hot kernel.
+    No (block_m, block_m) one-hot, but the ``(I_mode, r_block)`` output
+    tile stays resident (double-buffered) across the whole sequential
+    scan, next to the (1, r_block) carry-in and carry-out blocks. Word
+    blocks, staging, the contribution tile and the resident factor tiles
+    as in the one-hot kernel.
     """
-    W = meta.enc.n_words
-    words = block_m * W * 4
-    rows = block_m * 4
-    values = block_m * dtype_bytes
-    contrib = 3 * block_m * r_block * dtype_bytes   # krp + contrib + seg sums
-    out_resident = meta.dims[mode] * r_block * dtype_bytes
-    carry = r_block * dtype_bytes
-    factors = sum(I for m, I in enumerate(meta.dims)
-                  if m != mode) * r_block * dtype_bytes
-    return words + rows + values + contrib + out_resident + carry + factors
+    return (_stream_bytes(meta, block_m, meta.enc.ndim - 1)
+            + _tile_bytes(block_m, r_block, dtype_bytes)
+            + 2 * _tile_bytes(meta.dims[mode], r_block, dtype_bytes)
+            + 4 * _tile_bytes(1, r_block, dtype_bytes)
+            + _factor_bytes(meta, mode, r_block, dtype_bytes))
+
+
+def _phi_operand_bytes(meta: AltoMeta, mode: int, block_m: int, rank: int,
+                       dtype_bytes: int, pre_pi: bool) -> int:
+    """The Φ kernels' Khatri-Rao operand plus its index decode: the
+    double-buffered (block_m, R) Π tile under ALTO-PRE (no words read),
+    or the word blocks, staging and whole other factors under ALTO-OTF."""
+    if pre_pi:
+        return 2 * _tile_bytes(block_m, rank, dtype_bytes)
+    return (_stream_bytes(meta, block_m, meta.enc.ndim - 1)
+            + _factor_bytes(meta, mode, rank, dtype_bytes))
 
 
 def phi_oriented_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
@@ -226,36 +250,18 @@ def phi_oriented_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
 
     The Φ kernel has no rank tiling (the denominator ``<B[i_n,:], krp>``
     needs the full rank per element) and keeps the whole ``(I_mode, R)``
-    B operand resident every grid step, plus the gathered ``(block_m, R)``
-    B rows — the two terms the old MTTKRP-shaped model omitted (the
-    ROADMAP-flagged VMEM model gap).  Term by term:
-
-    * ``rows``/``words``/``values`` stream tiles;
-    * the ``(block_m, block_m)`` in-block segment one-hot;
-    * **resident B**: ``I_mode·R`` (whole factor, every step);
-    * **gathered B rows**: ``block_m·R``;
-    * krp + contrib intermediates: ``2·block_m·R``;
-    * the per-block segment-sum output tile: ``block_m·R``;
-    * Π operand: the streamed ``(block_m, R)`` Π tile under ALTO-PRE, or
-      the *fully resident* other factors (``Σ_{m≠mode} I_m·R``) under
-      ALTO-OTF (the kernel's BlockSpecs load them whole, not r_block-wide).
+    B operand resident. Term by term: the segment-id block, the
+    ``(block_m, block_m)`` one-hot, **resident B**, the contribution
+    tile, the double-buffered segment-sum output block, and the Π
+    operand (`_phi_operand_bytes`).
     """
-    W = meta.enc.n_words
-    words = block_m * W * 4
-    rows = block_m * 4
-    values = block_m * dtype_bytes
-    onehot = block_m * block_m * dtype_bytes
-    b_resident = meta.dims[mode] * rank * dtype_bytes
-    b_rows = block_m * rank * dtype_bytes
-    krp_contrib = 2 * block_m * rank * dtype_bytes
-    out = block_m * rank * dtype_bytes
-    if pre_pi:
-        operands = block_m * rank * dtype_bytes
-    else:
-        operands = sum(I for m, I in enumerate(meta.dims)
-                       if m != mode) * rank * dtype_bytes
-    return (words + rows + values + onehot + b_resident + b_rows
-            + krp_contrib + out + operands)
+    return (2 * block_m * 4
+            + _onehot_bytes(block_m, dtype_bytes)
+            + _tile_bytes(meta.dims[mode], rank, dtype_bytes)
+            + _tile_bytes(block_m, rank, dtype_bytes)
+            + 2 * _tile_bytes(block_m, rank, dtype_bytes)
+            + _phi_operand_bytes(meta, mode, block_m, rank, dtype_bytes,
+                                 pre_pi))
 
 
 def phi_oriented_carry_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
@@ -264,86 +270,85 @@ def phi_oriented_carry_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
     """Per-grid-step VMEM of the *scratch-carry fused Φ* kernel.
 
     Same full-rank accounting as :func:`phi_oriented_vmem_bytes` with the
-    (block_m, block_m) one-hot replaced by the carry pattern's resident
-    terms: the whole ``(I_mode, R)`` output block (written in place every
-    step) next to the already-resident ``(I_mode, R)`` B operand, one more
-    (block_m, R) segment-sum intermediate, and the (1, R) carry scratch.
+    one-hot and the segment ids replaced by the carry pattern's resident
+    terms: the double-buffered ``(I_mode, R)`` output block next to the
+    resident ``(I_mode, R)`` B operand, and the carry-in/out blocks.
     """
-    W = meta.enc.n_words
-    words = block_m * W * 4
-    rows = block_m * 4
-    values = block_m * dtype_bytes
-    b_resident = meta.dims[mode] * rank * dtype_bytes
-    b_rows = block_m * rank * dtype_bytes
-    krp_contrib = 2 * block_m * rank * dtype_bytes
-    seg_sums = block_m * rank * dtype_bytes
-    out_resident = meta.dims[mode] * rank * dtype_bytes
-    carry = rank * dtype_bytes
-    if pre_pi:
-        operands = block_m * rank * dtype_bytes
-    else:
-        operands = sum(I for m, I in enumerate(meta.dims)
-                       if m != mode) * rank * dtype_bytes
-    return (words + rows + values + b_resident + b_rows + krp_contrib
-            + seg_sums + out_resident + carry + operands)
+    return (_tile_bytes(meta.dims[mode], rank, dtype_bytes)
+            + _tile_bytes(block_m, rank, dtype_bytes)
+            + 2 * _tile_bytes(meta.dims[mode], rank, dtype_bytes)
+            + 4 * _tile_bytes(1, rank, dtype_bytes)
+            + _phi_operand_bytes(meta, mode, block_m, rank, dtype_bytes,
+                                 pre_pi))
 
 
 def phi_recursive_vmem_bytes(meta: AltoMeta, mode: int, rank: int,
                              dtype_bytes: int = 4,
-                             pre_pi: bool = False) -> int:
+                             pre_pi: bool = False,
+                             block_m: int = MIN_BLOCK_M) -> int:
     """Per-grid-step VMEM of the *recursive fused Φ* kernel — full rank.
 
-    Same accounting as :func:`phi_oriented_vmem_bytes` with the oriented
-    stream tiles replaced by the partition chunk, the segment one-hot by
-    the ``(chunk, T)`` Temp one-hot, and the output by the ``(T, R)``
-    partition Temp.  Nothing here is tunable (chunk is fixed by the
-    partition count, Φ runs full rank), so this footprint is advisory —
-    it is reported in the plan and used by the per-shard budget checks,
-    but cannot be shrunk by blocking.
+    Word blocks and the staging of all N coordinates (the target mode
+    indexes Temp and B), the double-buffered ``(T, R)`` partition Temp,
+    resident B, and the Π tile or resident other factors. Nothing here
+    is tunable but block_m (Φ runs full rank), so this footprint is
+    advisory — it is reported in the plan and used by the per-shard
+    budget checks.
     """
-    chunk = _chunk_rows(meta)
     T = meta.temp_rows[mode]
-    W = meta.enc.n_words
-    words = chunk * W * 4
-    values = chunk * dtype_bytes
-    onehot = chunk * T * dtype_bytes
-    b_resident = meta.dims[mode] * rank * dtype_bytes
-    b_rows = chunk * rank * dtype_bytes
-    krp_contrib = 2 * chunk * rank * dtype_bytes
-    temp = T * rank * dtype_bytes
     if pre_pi:
-        operands = chunk * rank * dtype_bytes
+        operands = 2 * _tile_bytes(block_m, rank, dtype_bytes)
     else:
-        operands = sum(I for m, I in enumerate(meta.dims)
-                       if m != mode) * rank * dtype_bytes
-    return (words + values + onehot + b_resident + b_rows + krp_contrib
-            + temp + operands)
+        operands = _factor_bytes(meta, mode, rank, dtype_bytes)
+    return (_stream_bytes(meta, block_m, meta.enc.ndim)
+            + 2 * _tile_bytes(T, rank, dtype_bytes)
+            + _tile_bytes(meta.dims[mode], rank, dtype_bytes)
+            + operands)
 
 
-def _divisors_desc(n: int) -> list[int]:
-    out = [d for d in range(1, n + 1) if n % d == 0]
-    return out[::-1]
+def rank_tiles(rank: int) -> list[int]:
+    """Rank tiles a kernel can run, largest first: the whole rank, then
+    every 128-multiple that divides it (a lane-aligned minor block)."""
+    return [rank] + [d for d in range(rank - LANES, 0, -LANES)
+                     if d % LANES == 0 and rank % d == 0]
+
+
+def block_sizes() -> list[int]:
+    """Nonzero block sizes the plan may emit, largest first."""
+    out, bm = [], MAX_BLOCK_M
+    while bm >= MIN_BLOCK_M:
+        out.append(bm)
+        bm //= 2
+    return out
+
+
+def _largest_fitting_tile(model, rank: int, vmem_limit: int) -> int:
+    """Largest rank tile whose footprint ``model(rb)`` fits the budget.
+
+    Always returns a tile the kernels can run (`rank_tiles`); if even the
+    smallest overflows, the budget is advisory and that tile is returned
+    — the kernel still compiles, Mosaic just may refuse its VMEM.
+    """
+    tiles = rank_tiles(rank)
+    for rb in tiles:
+        if model(rb) <= vmem_limit:
+            return rb
+    return tiles[-1]
 
 
 def choose_rank_block(meta: AltoMeta, mode: int, rank: int,
                       dtype_bytes: int = 4,
                       vmem_limit: int = VMEM_BYTES) -> int:
-    """Largest divisor of ``rank`` whose recursive footprint fits VMEM.
-
-    Always returns a divisor, so `ops.mttkrp` never sees a partial rank
-    tile; if even r_block=1 overflows (huge Temp intervals) the budget is
-    advisory and 1 is returned — the kernel still compiles, just spills.
-    """
-    for rb in _divisors_desc(rank):
-        if recursive_vmem_bytes(meta, mode, rb, dtype_bytes) <= vmem_limit:
-            return rb
-    return 1
+    """Largest rank tile whose recursive footprint fits VMEM."""
+    return _largest_fitting_tile(
+        lambda rb: recursive_vmem_bytes(meta, mode, rb, dtype_bytes),
+        rank, vmem_limit)
 
 
 def choose_rank_block_oriented(meta: AltoMeta, mode: int, rank: int,
                                dtype_bytes: int = 4,
                                vmem_limit: int = VMEM_BYTES) -> int:
-    """Largest divisor of ``rank`` whose *oriented* footprint fits VMEM.
+    """Largest rank tile whose *oriented* footprint fits VMEM.
 
     Sized at the minimum nonzero block so the rank tile is constrained by
     the resident factor tiles (the term that actually scales with rank),
@@ -351,34 +356,30 @@ def choose_rank_block_oriented(meta: AltoMeta, mode: int, rank: int,
     never runs that kernel. `choose_block_m` then shrinks the block to
     fit the chosen tile.
     """
-    for rb in _divisors_desc(rank):
-        if oriented_vmem_bytes(meta, mode, MIN_BLOCK_M, rb,
-                               dtype_bytes) <= vmem_limit:
-            return rb
-    return 1
+    return _largest_fitting_tile(
+        lambda rb: oriented_vmem_bytes(meta, mode, MIN_BLOCK_M, rb,
+                                       dtype_bytes), rank, vmem_limit)
 
 
 def choose_rank_block_carry(meta: AltoMeta, mode: int, rank: int,
                             dtype_bytes: int = 4,
                             vmem_limit: int = VMEM_BYTES) -> int:
-    """Largest divisor of ``rank`` whose *carry* footprint fits VMEM.
+    """Largest rank tile whose *carry* footprint fits VMEM.
 
     The carry kernel's resident ``(I_mode, r_block)`` output tile makes
     the rank tile the lever that actually bounds its footprint, so the
     tile is sized at the minimum nonzero block like the oriented sibling.
     """
-    for rb in _divisors_desc(rank):
-        if oriented_carry_vmem_bytes(meta, mode, MIN_BLOCK_M, rb,
-                                     dtype_bytes) <= vmem_limit:
-            return rb
-    return 1
+    return _largest_fitting_tile(
+        lambda rb: oriented_carry_vmem_bytes(meta, mode, MIN_BLOCK_M, rb,
+                                             dtype_bytes), rank, vmem_limit)
 
 
 def carry_fits_vmem(meta: AltoMeta, mode: int, rank: int,
                     dtype_bytes: int = 4,
                     vmem_limit: int = VMEM_BYTES) -> bool:
     """True iff the scratch-carry kernel is feasible for this mode at all
-    (smallest tiling: ``r_block=1``, ``MIN_BLOCK_M``).
+    (smallest tiling: the smallest rank tile, ``MIN_BLOCK_M``).
 
     Unlike the other budgets this one is a hard *routing* gate, not
     advisory: the carry kernel's whole advantage is the VMEM-resident
@@ -386,7 +387,8 @@ def carry_fits_vmem(meta: AltoMeta, mode: int, rank: int,
     traversal should route to the one-hot merge path instead of
     spilling — `heuristics.choose_oriented_variant` consumes this.
     """
-    return oriented_carry_vmem_bytes(meta, mode, MIN_BLOCK_M, 1,
+    return oriented_carry_vmem_bytes(meta, mode, MIN_BLOCK_M,
+                                     rank_tiles(rank)[-1],
                                      dtype_bytes) <= vmem_limit
 
 
@@ -508,7 +510,7 @@ def choose_block_m(meta: AltoMeta, mode: int, r_block: int,
                    pre_pi: bool = False,
                    traversal: heuristics.Traversal =
                    heuristics.Traversal.OUTPUT_ORIENTED) -> int:
-    """Largest power-of-two nonzero block for the oriented kernels.
+    """Largest nonzero block (`block_sizes`) for the oriented kernels.
 
     The oriented stream is padded to a multiple of block_m by `ops`, so the
     choice is free of divisibility constraints on nnz.  ``traversal``
@@ -544,10 +546,10 @@ def choose_block_m(meta: AltoMeta, mode: int, r_block: int,
             return False
         return True
 
-    bm = MAX_BLOCK_M
-    while bm > MIN_BLOCK_M and not fits(bm):
-        bm //= 2
-    return bm
+    for bm in block_sizes():
+        if fits(bm):
+            return bm
+    return MIN_BLOCK_M
 
 
 def phi_constraint_active(meta: AltoMeta, mode: int, rank: int,
@@ -580,9 +582,10 @@ def _mode_plan(meta: AltoMeta, mode: int, rank: int,
                dtype_bytes: int, pre_pi: bool) -> ModePlan:
     """Assemble a ModePlan with both kernel footprints filled in."""
     if traversal is heuristics.Traversal.RECURSIVE:
-        vm = recursive_vmem_bytes(meta, mode, r_block, dtype_bytes)
+        vm = recursive_vmem_bytes(meta, mode, r_block, dtype_bytes,
+                                  block_m=block_m)
         phi_vm = phi_recursive_vmem_bytes(meta, mode, rank, dtype_bytes,
-                                          pre_pi=pre_pi)
+                                          pre_pi=pre_pi, block_m=block_m)
     else:
         vm = _mttkrp_vmem_model(traversal)(meta, mode, block_m, r_block,
                                            dtype_bytes)
@@ -633,8 +636,11 @@ def static_mode_plan(meta: AltoMeta, mode: int, rank: int, *,
     else:
         rb = choose_rank_block_oriented(meta, mode, rank, dtype_bytes,
                                         vmem_limit)
-    bm = choose_block_m(meta, mode, rb, dtype_bytes, vmem_limit,
-                        rank=rank, pre_pi=pre_pi, traversal=traversal)
+    if traversal is heuristics.Traversal.RECURSIVE:
+        bm = MIN_BLOCK_M    # larger blocks only mean fewer grid steps
+    else:
+        bm = choose_block_m(meta, mode, rb, dtype_bytes, vmem_limit,
+                            rank=rank, pre_pi=pre_pi, traversal=traversal)
     return _mode_plan(meta, mode, rank, traversal, rb, bm, dtype_bytes,
                       pre_pi)
 
@@ -679,12 +685,10 @@ def candidate_mode_plans(meta: AltoMeta, mode: int, rank: int, *,
                   else heuristics.candidate_traversals(meta, mode))
     for traversal in traversals:
         if traversal is heuristics.Traversal.RECURSIVE:
-            # block_m is dead for the recursive kernel; keep the static
-            # block so candidates differ only in what the kernel reads.
-            for rb in _divisors_desc(rank):
+            for rb in rank_tiles(rank):
                 if recursive_vmem_bytes(meta, mode, rb,
                                         dtype_bytes) <= vmem_limit:
-                    add(traversal, rb, static.block_m)
+                    add(traversal, rb, MIN_BLOCK_M)
         else:
             if (traversal is heuristics.Traversal.ORIENTED_CARRY
                     and not carry_fits_vmem(meta, mode, rank, dtype_bytes,
@@ -699,12 +703,11 @@ def candidate_mode_plans(meta: AltoMeta, mode: int, rank: int, *,
                                                 dtype_bytes, vmem_limit,
                                                 pre_pi=pre_pi,
                                                 traversal=traversal)
-            for rb in _divisors_desc(rank):
+            for rb in rank_tiles(rank):
                 if mttkrp_model(meta, mode, MIN_BLOCK_M, rb,
                                 dtype_bytes) > vmem_limit:
                     continue
-                bm = MAX_BLOCK_M
-                while bm >= MIN_BLOCK_M:
+                for bm in block_sizes():
                     if (mttkrp_model(meta, mode, bm, rb,
                                      dtype_bytes) <= vmem_limit
                             and not (phi_binding and
@@ -713,7 +716,6 @@ def candidate_mode_plans(meta: AltoMeta, mode: int, rank: int, *,
                                          dtype_bytes,
                                          pre_pi=pre_pi) > vmem_limit)):
                         add(traversal, rb, bm)
-                    bm //= 2
     if max_candidates is not None and len(out) > max_candidates:
         out = out[:max_candidates]
     return tuple(out)
@@ -740,9 +742,8 @@ def make_plan(meta: AltoMeta, rank: int, *, backend: str | None = None,
     traversal's partition intervals overlap arbitrarily across devices —
     the one-hot-vs-carry refinement still applies per mode, and carry
     shards run the scratch-carry kernel locally under ``shard_map``)
-    and the VMEM budget is divided by the shard count (see module
-    docstring), so the shard-local Pallas tiles are sized for the
-    per-device slice of the stream.
+    and the shard-local Pallas tiles are sized against each device's
+    own VMEM (see module docstring).
 
     ``tune`` selects between the analytic model and measured plans
     (`core.autotune`, persisted in the on-disk plan store):
@@ -775,7 +776,7 @@ def make_plan(meta: AltoMeta, rank: int, *, backend: str | None = None,
 
     A store hit costs **zero timing runs** — the measured plan
     round-trips across processes through the store file
-    (``$REPRO_PLAN_CACHE`` or ``~/.cache/repro/plans.json``).
+    (``$REPRO_PLAN_CACHE`` or the checkout's ``.cache/plans.json``).
     """
     backend = backend or default_backend()
     if backend not in ("pallas", "reference"):
@@ -791,6 +792,9 @@ def make_plan(meta: AltoMeta, rank: int, *, backend: str | None = None,
         raise ValueError("out-of-core streaming does not compose with "
                          "mesh-sharded plans yet (shard first, then size "
                          "device_bytes per shard)")
+    if mesh is not None:
+        from repro.dist.meshes import auto_axes
+        mesh = auto_axes(mesh)
     if tune != "off":
         from repro.core import autotune
         tuned = autotune.tuned_plan(
@@ -805,10 +809,6 @@ def make_plan(meta: AltoMeta, rank: int, *, backend: str | None = None,
             store_path=store_path)
         if tuned is not None:
             return tuned
-    n_shards = 1
-    if mesh is not None:
-        n_shards = int(mesh.shape[mesh.axis_names[0]])
-        vmem_limit = max(1, vmem_limit // n_shards)
     pi_policy = heuristics.choose_pi_policy(
         meta, rank, value_bytes=dtype_bytes, fast_mem_bytes=fast_mem_bytes)
     modes = tuple(
@@ -943,7 +943,7 @@ def execute_mttkrp(plan: ExecutionPlan, at: AltoTensor,
                                        r_block=mp.r_block,
                                        interpret=plan.interpret)
         return ops.mttkrp(at, factors, mode, r_block=mp.r_block,
-                          interpret=plan.interpret)
+                          block_m=mp.block_m, interpret=plan.interpret)
     # reference backend: both oriented variants are the same sorted
     # segment_sum — the carry is a kernel-level distinction.
     if oriented:
@@ -1003,7 +1003,7 @@ def execute_phi(plan: ExecutionPlan, at: AltoTensor,
                                           eps=eps, block_m=mp.block_m,
                                           interpret=plan.interpret)
         return ops.cpapr_phi(at, B, mode, factors=factors, pi=pi, eps=eps,
-                             interpret=plan.interpret)
+                             block_m=mp.block_m, interpret=plan.interpret)
     # reference backend: pure-jnp traversals. Under ALTO-PRE the index
     # decode is dead work (the Pallas kernel skips it too): the oriented
     # view already materializes the target rows, so only the OTF path —

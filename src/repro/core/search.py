@@ -367,9 +367,8 @@ def mode_pool(meta: AltoMeta, mode: int, rank: int, *,
         force_carry=True, pre_pi=pre_pi)
     pool = [static]
     seen = {(static.r_block, static.block_m)}
-    for rb in plan_mod._divisors_desc(rank):
-        bm = plan_mod.MAX_BLOCK_M
-        while bm >= plan_mod.MIN_BLOCK_M:
+    for rb in plan_mod.rank_tiles(rank):
+        for bm in plan_mod.block_sizes():
             if ((rb, bm) not in seen
                     and plan_mod.oriented_carry_vmem_bytes(
                         meta, mode, bm, rb, dtype_bytes) <= vmem_limit):
@@ -377,7 +376,6 @@ def mode_pool(meta: AltoMeta, mode: int, rank: int, *,
                 pool.append(plan_mod._mode_plan(
                     meta, mode, rank, heuristics.Traversal.ORIENTED_CARRY,
                     rb, bm, dtype_bytes, pre_pi))
-            bm //= 2
     return _dedupe_pool(tuple(pool), backend, objective, streaming=True)
 
 

@@ -20,7 +20,7 @@ Contracts that make chunking bitwise-exact against the in-core
 * **Same padding rule.** The stream is padded once, host-side, to a
   multiple of :data:`STREAM_ALIGN` with `ops.pad_sorted_stream`'s rule —
   replicated final row/words, zero values (an empty stream pads with
-  zero rows/words). ``STREAM_ALIGN`` (1024, == ``plan.MAX_BLOCK_M``) is
+  zero rows/words). ``STREAM_ALIGN`` (2048, == ``plan.MAX_BLOCK_M``) is
   a multiple of every legal ``block_m``, and the padded prefix of length
   ``ceil(Mp/block_m)·block_m`` is element-for-element what
   `ops.pad_sorted_stream` would have produced at that ``block_m`` —
@@ -52,7 +52,7 @@ from repro.core.alto import AltoMeta, AltoTensor, OrientedView
 # One alignment for every host stream: a multiple of every legal oriented
 # block_m (powers of two in [plan.MIN_BLOCK_M, plan.MAX_BLOCK_M]), so one
 # padded copy serves any tiling. Must equal plan.MAX_BLOCK_M.
-STREAM_ALIGN = 1024
+STREAM_ALIGN = 2048
 
 
 class StreamIntegrityError(RuntimeError):

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import threading
 
@@ -95,8 +96,15 @@ def _view_bytes(v) -> int:
                for a in (v.rows, v.words, v.values, v.perm))
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _u32_mix(x: jnp.ndarray, salt: int) -> jnp.ndarray:
-    """Order-sensitive u32 checksum (wrapping arithmetic, eager jnp)."""
+    """Order-sensitive u32 checksum (wrapping arithmetic).
+
+    One jitted program: run op by op, the ``ravel`` of a narrow (M, W)
+    array would be its own executable and relayout the array into
+    128-lane tiles on a TPU (~40 GB for a 77M-nonzero, two-word stream).
+    """
+    x = x.ravel().astype(jnp.uint32)
     idx = jnp.arange(x.shape[0], dtype=jnp.uint32)
     mixed = (x ^ (idx * jnp.uint32(0x9E3779B1))) * jnp.uint32(salt)
     return jnp.sum(mixed, dtype=jnp.uint32)
@@ -113,10 +121,10 @@ def fingerprint(at: AltoTensor) -> tuple:
     """
     fp = getattr(at, _FP_ATTR, None)
     if fp is None:
-        w = _u32_mix(at.words.ravel().astype(jnp.uint32), 0x85EBCA6B)
+        w = _u32_mix(at.words, 0x85EBCA6B)
         # f32 -> (M,) u32; f64 -> (M, 2) u32: ravel covers both widths.
         v_bits = jax.lax.bitcast_convert_type(at.values, jnp.uint32)
-        v = _u32_mix(v_bits.ravel(), 0xC2B2AE35)
+        v = _u32_mix(v_bits, 0xC2B2AE35)
         fp = (at.meta, at.words.shape[0], int(w), int(v))
         at._ingest_fingerprint = fp
     return fp

@@ -49,8 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import alto
 from repro.core import cpals
@@ -175,10 +174,10 @@ def sharded_mttkrp(plan: plan_mod.ExecutionPlan, at: AltoTensor,
     local = functools.partial(local_mttkrp, plan, mode)
 
     def build():
-        @functools.partial(shard_map, mesh=plan.mesh,
+        @functools.partial(jax.shard_map, mesh=plan.mesh,
                            in_specs=(P(ax), P(ax), P(ax), P()),
                            out_specs=P(),
-                           check_rep=False)  # pallas_call has no rep rule
+                           check_vma=False)  # pallas_call has no rep rule
         def sharded(rows, words, values, factors):
             return jax.lax.psum(local(rows, words, values, factors), ax)
 
@@ -210,10 +209,10 @@ def sharded_phi(plan: plan_mod.ExecutionPlan, at: AltoTensor,
 
     def build():
         @functools.partial(
-            shard_map, mesh=plan.mesh,
+            jax.shard_map, mesh=plan.mesh,
             in_specs=(P(ax), P(ax), P(ax), P(), P(), pi_spec),
             out_specs=P(),
-            check_rep=False)              # pallas_call has no rep rule
+            check_vma=False)              # pallas_call has no rep rule
         def sharded(rows, words, values, B, factors, pi):
             return jax.lax.psum(
                 local(rows, words, values, B, factors=factors, pi=pi), ax)
@@ -231,6 +230,33 @@ def sharded_phi(plan: plan_mod.ExecutionPlan, at: AltoTensor,
               list(factors) if factors is not None else None, pi)
 
 
+def shard_views(plan: plan_mod.ExecutionPlan,
+                views: dict[int, OrientedView]) -> dict[int, OrientedView]:
+    """Place each oriented view row-range-sharded over the plan's mesh.
+
+    The stream is padded once to the plan's shard multiple (the padding
+    rule of `sharded_mttkrp`, the Π permutation replicating its final
+    entry like the rows) and cut into contiguous per-device slices by a
+    `NamedSharding`, so every sweep's `shard_map` finds its slices in
+    place instead of resharding a view that sits on one device.
+    """
+    ax = plan.mesh_axis
+    out = {}
+    for mode, v in views.items():
+        rows, words, values, _ = _pad_stream(v.rows, v.words, v.values,
+                                             _shard_mult(plan, mode))
+        pad = rows.shape[0] - v.rows.shape[0]
+        perm = (jnp.concatenate([v.perm, jnp.broadcast_to(v.perm[-1:],
+                                                          (pad,))])
+                if pad and v.perm.shape[0] else v.perm)
+        place = functools.partial(jax.device_put,
+                                  device=NamedSharding(plan.mesh, P(ax)))
+        out[mode] = OrientedView(meta=v.meta, mode=mode, rows=place(rows),
+                                 words=place(words), values=place(values),
+                                 perm=place(perm))
+    return out
+
+
 def sharded_gram(mesh, A: jnp.ndarray) -> jnp.ndarray:
     """AᵀA with the rows of ``A`` sharded over the mesh's first axis and
     the per-device Grams combined by ``psum`` (zero-row padding)."""
@@ -238,8 +264,8 @@ def sharded_gram(mesh, A: jnp.ndarray) -> jnp.ndarray:
     D = int(mesh.shape[ax])
 
     def build():
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P(ax),),
-                           out_specs=P(), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(ax),),
+                           out_specs=P(), check_vma=False)
         def sharded(A_shard):
             return jax.lax.psum(local_gram(A_shard), ax)
 
@@ -296,7 +322,7 @@ def sharded_append_delta(at: AltoTensor, coords, values, mesh, *,
     Dp = coords.shape[0]
 
     def build():
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P(ax),),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(ax),),
                            out_specs=P(ax))
         def sharded(c):
             return alto.linearize(enc, c)
@@ -354,7 +380,8 @@ def distributed_cp_als(x: SparseTensor | AltoTensor, rank: int, mesh, *,
     plan = plan_mod.make_plan(at.meta, rank, backend=backend,
                               interpret=interpret, mesh=mesh,
                               tune=tune, at=at)
+    views = shard_views(plan, plan_mod.build_views(at, plan))
     res = cpals.cp_als(at, rank, n_iters=n_iters, tol=tol, seed=seed,
-                       plan=plan, warm_start=warm_start,
-                       gram_fn=functools.partial(sharded_gram, mesh))
+                       plan=plan, views=views, warm_start=warm_start,
+                       gram_fn=functools.partial(sharded_gram, plan.mesh))
     return res.lam, res.factors, res.fits

@@ -37,10 +37,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.dist.meshes import auto_axes
 from repro.models import blocks as blk
 from repro.models import model as model_lib
 from repro.models.common import rmsnorm, unembed
@@ -126,8 +126,8 @@ def _pipe_hidden(cfg: ModelConfig, blocks, x_stack, positions, positions3,
         aux = jax.lax.psum(aux, ax)
         return out, aux
 
-    fn = shard_map(schedule, mesh=mesh,
-                   in_specs=(P(ax), P(), P(), P()), out_specs=(P(), P()))
+    fn = jax.shard_map(schedule, mesh=auto_axes(mesh),
+                       in_specs=(P(ax), P(), P(), P()), out_specs=(P(), P()))
     return fn(blocks, x_stack, positions, positions3)
 
 

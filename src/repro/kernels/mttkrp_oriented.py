@@ -1,67 +1,51 @@
 """Pallas TPU kernel: output-oriented MTTKRP / Φ segment reduction.
 
-The complement of the recursive one-hot-MXU kernel in `kernels/mttkrp.py`
-(paper §4.2, Fig. 8 right): nonzeros arrive permuted into ascending order
-of the target-mode row (`core.alto.oriented_view`), so conflict-free
-updates become a *sorted segment reduction*. This mirrors the conflict-free
+The complement of the recursive kernel in `kernels/mttkrp.py` (paper
+§4.2, Fig. 8 right): nonzeros arrive permuted into ascending order of the
+target-mode row (`core.alto.oriented_view`), so conflict-free updates
+become a *sorted segment reduction*. This mirrors the conflict-free
 segment-reduction designs of ALTO (arXiv:2102.10245) and Dynasor
-(arXiv:2309.09131), adapted to the TPU's no-atomics execution model:
+(arXiv:2309.09131), adapted to the TPU's no-atomics execution model. The
+sorted row stream is cut into `block_m`-element blocks, one grid step
+each, and two reductions are offered:
 
-  * the sorted row stream is cut into `block_m`-element blocks (one grid
-    step each — a blocked scan over the sorted rows);
-  * within a block, segment ids are the run-rank of each row
-    (``cumsum(rows[i] != rows[i-1])``, a VPU prefix scan), and the segment
-    sums are formed by ONE one-hot matmul on the MXU —
-    ``onehot(seg).T @ contrib`` — exactly like the recursive kernel's Temp
-    scatter but indexed by run rank instead of partition-interval offset,
-    so the operand is (block_m, block_m) regardless of the mode length;
-  * a row whose run crosses a block boundary yields one partial sum in
-    each adjacent block; the boundary carry is merged outside the kernel
-    by `ops.segment_merge`, which scatters every block's segment sums to
-    their global rows (at most one shared row per boundary — the paper's
-    "atomics only at partition boundaries", pull-based).
+**One-hot merge** (`*_partials_pallas`): a scalar loop writes every
+element's contribution row into a VMEM tile (the factor rows are loaded
+at coordinates staged in SMEM, `kernels.mttkrp`), and the block's segment
+sums are formed by ONE one-hot matmul on the MXU,
+``onehot(seg)ᵀ @ contrib``. The segment ids are the run rank of each row
+inside its block; they are fixed once the view exists, so the wrapper
+computes them outside the kernel (`run_rank_segments`). A row whose run
+crosses a block boundary yields one partial sum in each adjacent block;
+`ops.segment_merge` scatters every block's sums to their global rows —
+the paper's "atomics only at partition boundaries", pull-based.
 
-The Φ variant fuses the CP-APR model-update arithmetic (B-row gather,
-denominator dot, Poisson elementwise update — paper Alg. 5) ahead of the
-same segment reduction, for both Π policies (ALTO-PRE / ALTO-OTF).
+**Scratch carry** (`*_carry_pallas`, `*_carry_chunk_pallas`): partial
+sums ride *through* the sorted-stream scan on a sequential block grid.
+The ``(I_n, r_block)`` output tile stays VMEM-resident across the scan;
+the element loop keeps the open run's in-block sum ``acc`` and the carry
+from earlier blocks ``extra`` in registers and stores ``acc + extra``
+into the run's row at every element, so a closed run's row already holds
+its total and the open run leaves the block as the carry. The in-core
+kernel is the one-chunk case of the chunk kernel: the accumulator and
+the carry arrive as inputs (zeros and row −1 for a fresh scan) and leave
+as outputs, so an out-of-core stream threads them from chunk to chunk.
 
-VMEM per grid step (f32): block_m·(W + 2 + 2·r_block + block_m) +
-Σ_{m≠mode} I_m·r_block words — `core.plan.choose_block_m` sizes block_m so
-this fits the 16 MB budget (divided by the shard count for mesh-bearing
-plans, see `core.plan`).
-
-**Scratch-carry variant** (`*_carry_pallas`, paper §4.2's output-oriented
-reduction taken to its conclusion): the one-hot kernel above pays an
-O(block_m²) MXU matmul per block and materializes `(n_blocks, block_m, R)`
-partials to HBM that `ops.segment_merge` immediately re-scatters — an
-intermediate 10-100× larger than the final `(I_n, R)` output. ALTO
-(arXiv:2102.10245) and Dynasor (arXiv:2309.09131) instead carry partial
-sums *through* the sorted-stream scan. The carry kernels do exactly that
-on a **sequential 1-D block grid**:
-
-  * in-block segment sums come from a VPU scatter-add over the run-rank
-    ids (`zeros.at[seg].add(contrib)`) — no (block_m, block_m) one-hot;
-  * the `(I_n, r_block)` output tile stays VMEM-resident across the whole
-    scan (constant out index_map; `input_output_aliases` seeds it from a
-    zero buffer), and every *closed* run's total is scattered straight
-    into it — no partials buffer, no host-side merge pass;
-  * the block's final run is *open* (it may continue into the next
-    block): its partial sum rides a `(1, r_block)` VMEM scratch plus an
-    SMEM row id to the next grid step, where it either merges into the
-    first run or is flushed. Boundary carries therefore survive only at
-    *shard* boundaries, merged by the existing psum path in `dist.cpd`.
-
-Carry-vs-one-hot parity is bit-exact: within-block sums accumulate in the
-same element order, and the carry chain re-associates cross-block partials
-only by IEEE-commutative swaps (x+y == y+x bitwise), which
+Carry-vs-one-hot parity is bit-exact: the in-block sums accumulate in the
+same element order as the one-hot matmul, and the carry adds ``acc +
+extra`` where the merge adds block partials in block order — the same
+sums up to IEEE-commutative swaps (x+y == y+x bitwise), which
 `tests/test_oriented_carry.py` pins on adversarial run layouts.
 
+The Φ variants fuse the CP-APR model update (B-row load, denominator dot,
+Poisson elementwise update — paper Alg. 5) into the same element loop,
+for both Π policies (ALTO-PRE streams Π rows; ALTO-OTF decodes the index
+words and loads factor rows).
+
 Invariants: the input stream is row-sorted with length an exact multiple
-of block_m (callers pad — `ops` / `dist.cpd`); row ids are global, and the
-carry-merge correctness condition is that `ops.segment_merge` reproduces
-`run_rank_segments` bit-for-bit — which also makes the per-block partials
-safe to compute on shard-local slices and combine by psum
-(`repro.dist.cpd`); all tiling comes from static, hashable plan metadata.
+of block_m (callers pad — `ops` / `dist.cpd`); row ids are global, so
+per-shard results combine by psum (`repro.dist.cpd`); all tiling comes
+from static, hashable plan metadata.
 """
 from __future__ import annotations
 
@@ -73,17 +57,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import AltoEncoding
-from repro.kernels.mttkrp import _decode
-
-DEFAULT_BLOCK_M = 256
+from repro.kernels.cpapr_phi import phi_row
+from repro.kernels.mttkrp import (DEFAULT_BLOCK_M, _for_each, _krp_row,
+                                  _stage_coords, compiler_params, resident,
+                                  stage_scratch, word_columns)
 
 
 def run_rank_segments(rows):
     """Run-rank segment ids along the last axis of a sorted row array.
 
-    Shared between the kernels and `ops.segment_merge`: the merge's
-    scatter map must reproduce this segmentation bit-for-bit, so there is
-    exactly one implementation.
+    Shared between the one-hot kernel's wrapper and `ops.segment_merge`:
+    the merge's scatter map must reproduce this segmentation bit-for-bit,
+    so there is exactly one implementation.
     """
     block_m = rows.shape[-1]
     idx = jax.lax.iota(jnp.int32, block_m)
@@ -92,41 +77,63 @@ def run_rank_segments(rows):
     return jnp.cumsum(is_new, axis=-1)
 
 
-def _block_segments(rows):
-    """Kernel-side: segment ids + lane iota of a (block_m,) row vector."""
-    return run_rank_segments(rows), jax.lax.iota(jnp.int32, rows.shape[0])
+def _check_stream(M: int, block_m: int, R: int, rb: int) -> None:
+    if M % block_m:
+        raise ValueError(f"nnz {M} not a multiple of block_m {block_m}")
+    if R % rb:
+        raise ValueError(f"rank {R} not a multiple of r_block {rb}")
 
 
-def _segment_matmul(seg, idx, contrib):
+def _fill_contrib(block_m, c_ref, contrib):
+    """Phase 1 of every oriented kernel: element j's contribution row
+    into row j of the VMEM tile ``c_ref``. Keeping the reduction in a
+    separate pass keeps each sum's rounding independent of how the
+    contribution was formed (no fused multiply-add across the two)."""
+    def body(j, state):
+        c_ref[pl.ds(j, 1), :] = contrib(j)
+        return state
+
+    _for_each(block_m, body, 0)
+
+
+def _segment_matmul(seg, contrib):
     """Per-segment sums via one one-hot matmul: (block_m, r_block)."""
-    onehot = (seg[:, None] == idx[None, :]).astype(contrib.dtype)
+    block_m = seg.shape[0]
+    onehot = (seg[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (block_m, block_m), 1)).astype(contrib.dtype)
+    # Contracting the element axis of an element-major one-hot keeps the
+    # per-segment sums in element order, bit-for-bit the carry scan's.
     return jax.lax.dot_general(
         onehot, contrib, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(contrib.dtype)
 
 
-def _mttkrp_oriented_kernel(enc: AltoEncoding, mode: int,
-                            rows_ref, words_ref, vals_ref, *refs):
+def _operand_specs(pre_pi, mode, block_m, R, factors, pi, stream_map,
+                   const_map):
+    """In-specs/args for the Φ Khatri-Rao operand: the streamed Π tile
+    (ALTO-PRE) or every other mode's whole factor (ALTO-OTF)."""
+    if pre_pi:
+        return [pl.BlockSpec((block_m, R), stream_map)], [pi]
+    others = [f for m, f in enumerate(factors) if m != mode]
+    return [resident(f.shape, const_map) for f in others], others
+
+
+# ---------------------------------------------------------------------------
+# One-hot merge: per-block segment sums, merged outside the kernel
+# ---------------------------------------------------------------------------
+
+def _mttkrp_oriented_kernel(enc: AltoEncoding, mode: int, block_m: int,
+                            seg_ref, *refs):
     """Grid step: one (nonzero block, rank tile) -> in-block segment sums."""
-    factor_refs = refs[:-1]
-    out_ref = refs[-1]
-    rows = rows_ref[...]                      # (block_m,) ascending
-    words = words_ref[...]                    # (block_m, W)
-    vals = vals_ref[...]                      # (block_m,)
-    coords = _decode(enc, words)              # N × (block_m,)
-
-    krp = None                                # Khatri-Rao rows (block_m, rb)
-    fi = 0
-    for m in range(enc.ndim):
-        if m == mode:
-            continue
-        gathered = jnp.take(factor_refs[fi][...], coords[m], axis=0)
-        krp = gathered if krp is None else krp * gathered
-        fi += 1
-    contrib = vals[:, None] * krp             # (block_m, rb)
-
-    seg, idx = _block_segments(rows)
-    out_ref[0] = _segment_matmul(seg, idx, contrib)
+    W, n_other = enc.n_words, enc.ndim - 1
+    word_refs, vals_ref = refs[:W], refs[W]
+    factor_refs = refs[W + 1:W + 1 + n_other]
+    out_ref, c_ref, cv_ref, cs_ref, sem = refs[W + 1 + n_other:]
+    others = [m for m in range(enc.ndim) if m != mode]
+    _stage_coords(enc, others, word_refs, cv_ref, cs_ref, sem)
+    _fill_contrib(block_m, c_ref,
+                  lambda j: vals_ref[j] * _krp_row(j, cs_ref, factor_refs))
+    out_ref[0] = _segment_matmul(seg_ref[...], c_ref[...])
 
 
 def mttkrp_oriented_partials_pallas(enc: AltoEncoding, mode: int,
@@ -143,62 +150,64 @@ def mttkrp_oriented_partials_pallas(enc: AltoEncoding, mode: int,
     `ops.segment_merge` scatters the slots to global rows and thereby
     merges boundary carries.
     """
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"nnz {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
+    M = words.shape[0]
     R = factors[0].shape[1]
     rb = r_block or R
-    if R % rb:
-        raise ValueError(f"rank {R} not a multiple of r_block {rb}")
+    _check_stream(M, block_m, R, rb)
+    n_blocks = M // block_m
+    seg = run_rank_segments(rows.reshape(n_blocks, block_m)).reshape(M)
     others = [f for m, f in enumerate(factors) if m != mode]
+    stream = pl.BlockSpec((block_m,), lambda b, r: (b,))
 
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda b, r: (b,)),           # rows
-        pl.BlockSpec((block_m, W), lambda b, r: (b, 0)),       # words
-        pl.BlockSpec((block_m,), lambda b, r: (b,)),           # values
-    ] + [
-        pl.BlockSpec((f.shape[0], rb), lambda b, r: (0, r)) for f in others
-    ]
+    in_specs = ([stream] * (1 + enc.n_words)                     # seg, words
+                + [pl.BlockSpec((block_m,), lambda b, r: (b,),
+                                memory_space=pltpu.SMEM)]        # values
+                + [resident((f.shape[0], rb), lambda b, r: (0, r))
+                   for f in others])
     return pl.pallas_call(
-        functools.partial(_mttkrp_oriented_kernel, enc, mode),
+        functools.partial(_mttkrp_oriented_kernel, enc, mode, block_m),
         grid=(n_blocks, R // rb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_m, rb), lambda b, r: (b, 0, r)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, block_m, R),
                                        factors[0].dtype),
+        scratch_shapes=[pltpu.VMEM((block_m, rb), factors[0].dtype)]
+        + stage_scratch(enc.ndim - 1, block_m),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(rows, words, values, *others)
+    )(seg, *word_columns(words), values, *others)
 
 
 def _phi_oriented_kernel(enc: AltoEncoding, mode: int, eps: float,
-                         pre_pi: bool,
-                         rows_ref, words_ref, vals_ref, b_ref, *refs):
+                         pre_pi: bool, block_m: int,
+                         seg_ref, rows_ref, vals_ref, b_ref, *refs):
     """Grid step: fused Φ update + in-block segment sums (full rank)."""
-    out_ref = refs[-1]
-    rows = rows_ref[...]
-    vals = vals_ref[...]
-
+    n_other = enc.ndim - 1
     if pre_pi:
-        krp = refs[0][...]                    # Π rows (block_m, R)
+        pi_ref, out_ref, c_ref = refs
     else:
-        # OTF only: the index decode is dead work under ALTO-PRE.
-        coords = _decode(enc, words_ref[...])
-        krp = None
-        fi = 0
-        for m in range(enc.ndim):
-            if m == mode:
-                continue
-            gathered = jnp.take(refs[fi][...], coords[m], axis=0)
-            krp = gathered if krp is None else krp * gathered
-            fi += 1
+        W = enc.n_words
+        word_refs, factor_refs = refs[:W], refs[W:W + n_other]
+        out_ref, c_ref, cv_ref, cs_ref, sem = refs[W + n_other:]
+        _stage_coords(enc, [m for m in range(enc.ndim) if m != mode],
+                      word_refs, cv_ref, cs_ref, sem)
 
-    b_rows = jnp.take(b_ref[...], rows, axis=0)        # (block_m, R)
-    denom = jnp.maximum(jnp.sum(b_rows * krp, axis=-1), eps)
-    contrib = (vals / denom)[:, None] * krp
+    def contrib(j):
+        krp = (pi_ref[pl.ds(j, 1), :] if pre_pi
+               else _krp_row(j, cs_ref, factor_refs))
+        b_row = b_ref[pl.ds(rows_ref[j], 1), :]
+        return phi_row(vals_ref, j, b_row, krp, eps)
 
-    seg, idx = _block_segments(rows)
-    out_ref[0] = _segment_matmul(seg, idx, contrib)
+    _fill_contrib(block_m, c_ref, contrib)
+    out_ref[0] = _segment_matmul(seg_ref[...], c_ref[...])
+
+
+def _phi_stream_specs(enc, pre_pi, block_m, B, stream_map, const_map):
+    """Shared Φ in-specs: seg/rows/values (+ words under OTF), B."""
+    smem = pl.BlockSpec((block_m,), stream_map, memory_space=pltpu.SMEM)
+    words = [] if pre_pi else [pl.BlockSpec((block_m,), stream_map)
+                               ] * enc.n_words
+    return smem, words, resident(B.shape, const_map)
 
 
 def phi_oriented_partials_pallas(enc: AltoEncoding, mode: int, eps: float,
@@ -216,326 +225,111 @@ def phi_oriented_partials_pallas(enc: AltoEncoding, mode: int, eps: float,
     pre_pi = pi is not None
     if pre_pi == (factors is not None):
         raise ValueError("pass exactly one of pi= / factors=")
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"nnz {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
+    M = words.shape[0]
     R = B.shape[1]
-
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # rows
-        pl.BlockSpec((block_m, W), lambda b: (b, 0)),          # words
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # values
-        pl.BlockSpec(B.shape, lambda b: (0, 0)),               # B
-    ]
-    args = [rows, words, values, B]
-    if pre_pi:
-        in_specs.append(pl.BlockSpec((block_m, R), lambda b: (b, 0)))
-        args.append(pi)
-    else:
-        others = [f for m, f in enumerate(factors) if m != mode]
-        in_specs += [pl.BlockSpec(f.shape, lambda b: (0, 0)) for f in others]
-        args += others
-
+    _check_stream(M, block_m, R, R)
+    n_blocks = M // block_m
+    seg = run_rank_segments(rows.reshape(n_blocks, block_m)).reshape(M)
+    smem, word_specs, b_spec = _phi_stream_specs(
+        enc, pre_pi, block_m, B, lambda b: (b,), lambda b: (0, 0))
+    op_specs, op_args = _operand_specs(pre_pi, mode, block_m, R, factors,
+                                       pi, lambda b: (b, 0),
+                                       lambda b: (0, 0))
+    scratch = [pltpu.VMEM((block_m, R), B.dtype)]
+    if not pre_pi:
+        scratch += stage_scratch(enc.ndim - 1, block_m)
     return pl.pallas_call(
-        functools.partial(_phi_oriented_kernel, enc, mode, eps, pre_pi),
+        functools.partial(_phi_oriented_kernel, enc, mode, eps, pre_pi,
+                          block_m),
         grid=(n_blocks,),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((block_m,), lambda b: (b,)), smem, smem,
+                  b_spec] + word_specs + op_specs,
         out_specs=pl.BlockSpec((1, block_m, R), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, block_m, R), B.dtype),
+        scratch_shapes=scratch,
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
-    )(*args)
+    )(seg, rows, values, B,
+      *([] if pre_pi else word_columns(words)), *op_args)
 
 
 # ---------------------------------------------------------------------------
-# Scratch-carry sequential-grid variant (no partials buffer, no host merge)
+# Scratch carry: one sequential scan, resident output, chunkable
 # ---------------------------------------------------------------------------
 
-def _carry_step(b, n_blocks, rows, contrib, out_ref, crow_ref, cval_ref,
-                carry_in=None, final=True, carry_out=None):
-    """One grid step of the scratch-carry scan, shared by MTTKRP and Φ.
+def _carry_scan(block_m, first, rows_ref, contrib, c_ref, acc_ref,
+                cin_row_ref, cin_val_ref, out_ref, cout_row_ref,
+                cout_val_ref, sem):
+    """One grid step of the carry scan, shared by MTTKRP and Φ.
 
-    ``b`` is the position along the sequential block axis. In-block
-    segment sums are formed by a scatter-add over the run-rank ids (the
-    accumulation visits elements in stream order, matching the one-hot
-    matmul bit-for-bit); closed runs land in the resident ``out_ref``
-    block, the open final run replaces the carry scratch. The carry from
-    the previous step either merges into this block's first run (same
-    row) or is flushed — commutative re-association only, so the chain
-    reproduces `ops.segment_merge`'s block-ordered adds bitwise.
-
-    Out-of-core extension (`core.plan` streaming): the scan can start
-    and stop mid-stream. ``carry_in`` is ``None`` for a fresh scan
-    (empty carry: row −1, zero value) or ``(row_ref, val_ref)`` holding
-    the open run handed in from the previous chunk; ``final`` is
-    statically False for non-final chunks, which suppresses the
-    stream-closing flush — the last block's open run exits through
-    ``carry_out`` ``(row_ref, val_ref)`` instead. A non-final last block
-    scatters the same masked zero to row 0 the in-core kernel's
-    non-last blocks do, so the chunked op sequence is identical
-    add-for-add to the in-core scan and parity stays bitwise.
+    The block's contributions go to the VMEM tile ``c_ref`` first
+    (`_fill_contrib`); the scan then reduces the tile's rows in element
+    order. At the scan's first block the resident output tile is loaded from
+    the accumulator (HBM, aliased onto the output) and the carry from
+    ``cin``; the carry then lives in the resident ``cout`` outputs.
+    Every element stores its run's running total, so nothing is left to
+    flush when the stream ends.
     """
-    block_m = rows.shape[0]
-
-    @pl.when(b == 0)
+    @pl.when(first)
     def _():
-        if carry_in is None:                   # fresh scan: empty carry
-            crow_ref[0] = -1
-            cval_ref[...] = jnp.zeros(cval_ref.shape, cval_ref.dtype)
-        else:                                  # resume the previous chunk
-            crow_ref[0] = carry_in[0][0]
-            cval_ref[...] = carry_in[1][...]
+        copy = pltpu.make_async_copy(acc_ref, out_ref, sem)
+        copy.start()
+        copy.wait()
+        cout_row_ref[0] = cin_row_ref[0]
+        cout_val_ref[...] = cin_val_ref[...]
 
-    prev_row = crow_ref[0]
-    prev_val = cval_ref[0]
+    _fill_contrib(block_m, c_ref, contrib)
+    zero = jnp.zeros(cout_val_ref.shape, cout_val_ref.dtype)
 
-    seg, idx = _block_segments(rows)
-    seg_sums = jnp.zeros(contrib.shape, contrib.dtype).at[seg].add(contrib)
-    seg_rows = jnp.zeros((block_m,), jnp.int32).at[seg].set(rows)
-    n_segs = seg[block_m - 1] + 1
+    def body(j, state):
+        run_row, acc, extra = state
+        r = rows_ref[j]
+        new = r != run_row
+        acc = jnp.where(new, zero, acc) + c_ref[pl.ds(j, 1), :]
+        extra = jnp.where(new, zero, extra)
+        out_ref[pl.ds(r, 1), :] = acc + extra
+        return r, acc, extra
 
-    zero = jnp.zeros_like(prev_val)
-    merge = prev_row == rows[0]                # open run continues here
-    seg_sums = seg_sums.at[0].add(jnp.where(merge, prev_val, zero))
-    flush = jnp.logical_and(prev_row >= 0, jnp.logical_not(merge))
-    flush_row = jnp.where(flush, prev_row, 0)
-    flush_val = jnp.where(flush, prev_val, zero)
-
-    new_val = jax.lax.dynamic_index_in_dim(seg_sums, n_segs - 1, 0,
-                                           keepdims=False)
-    if final:
-        last = b == n_blocks - 1
-        fin_row = jnp.where(last, rows[block_m - 1], 0)  # close the stream
-        fin_val = jnp.where(last, new_val, zero)
-    else:
-        # The stream continues into the next chunk: every block behaves
-        # like an in-core non-last block (masked zero to row 0).
-        fin_row = jnp.zeros((), jnp.int32)
-        fin_val = zero
-
-    # Closed runs + (up to) two carry flushes, one combined scatter-add
-    # into the resident output; masked slots add 0.0 to row 0, harmless.
-    closed = idx < n_segs - 1
-    srows = jnp.concatenate([jnp.where(closed, seg_rows, 0),
-                             flush_row[None], fin_row[None]])
-    svals = jnp.concatenate(
-        [jnp.where(closed[:, None], seg_sums, jnp.zeros_like(seg_sums)),
-         flush_val[None], fin_val[None]])
-    out_ref[...] = out_ref[...].at[srows].add(svals)
-
-    crow_ref[0] = rows[block_m - 1]
-    cval_ref[0] = new_val
-    if carry_out is not None:
-        carry_out[0][0] = rows[block_m - 1]
-        carry_out[1][0] = new_val
+    run_row, acc, extra = _for_each(
+        block_m, body, (cout_row_ref[0], zero, cout_val_ref[...]))
+    cout_row_ref[0] = run_row
+    cout_val_ref[...] = acc + extra
 
 
-def _mttkrp_carry_kernel(enc: AltoEncoding, mode: int,
-                         rows_ref, words_ref, vals_ref, *refs):
+def _carry_io(I_n, rb, R, dtype, tile_map):
+    """Out-specs/shapes of the carry scan: accumulator tile + carry."""
+    specs = [pl.BlockSpec((I_n, rb), tile_map),
+             pl.BlockSpec(memory_space=pltpu.SMEM),
+             pl.BlockSpec((1, rb), tile_map)]
+    shapes = [jax.ShapeDtypeStruct((I_n, R), dtype),
+              jax.ShapeDtypeStruct((1,), jnp.int32),
+              jax.ShapeDtypeStruct((1, R), dtype)]
+    return specs, shapes
+
+
+def _mttkrp_carry_kernel(enc: AltoEncoding, mode: int, block_m: int,
+                         rows_ref, *refs):
     """Grid step: (rank tile r, sorted block b) -> resident (I_n, rb)."""
-    factor_refs = refs[:-4]
-    out_ref, crow_ref, cval_ref = refs[-3], refs[-2], refs[-1]
-    # refs[-4] is the zero init buffer aliased onto out_ref — never read.
-    rows = rows_ref[...]
-    words = words_ref[...]
-    vals = vals_ref[...]
-    coords = _decode(enc, words)
+    W, n_other = enc.n_words, enc.ndim - 1
+    word_refs, vals_ref = refs[:W], refs[W]
+    cin_row_ref, cin_val_ref = refs[W + 1], refs[W + 2]
+    factor_refs = refs[W + 3:W + 3 + n_other]
+    acc_ref = refs[W + 3 + n_other]
+    (out_ref, cout_row_ref, cout_val_ref, c_ref, cv_ref, cs_ref, sem,
+     acc_sem) = refs[W + 4 + n_other:]
+    r_tile = pl.program_id(0)
+    rb = out_ref.shape[1]
+    _stage_coords(enc, [m for m in range(enc.ndim) if m != mode],
+                  word_refs, cv_ref, cs_ref, sem)
+    if rb != acc_ref.shape[1]:
+        acc_ref = acc_ref.at[:, pl.ds(r_tile * rb, rb)]
 
-    krp = None
-    fi = 0
-    for m in range(enc.ndim):
-        if m == mode:
-            continue
-        gathered = jnp.take(factor_refs[fi][...], coords[m], axis=0)
-        krp = gathered if krp is None else krp * gathered
-        fi += 1
-    contrib = vals[:, None] * krp              # (block_m, rb)
+    def contrib(j):
+        return vals_ref[j] * _krp_row(j, cs_ref, factor_refs)
 
-    _carry_step(pl.program_id(1), pl.num_programs(1), rows, contrib,
-                out_ref, crow_ref, cval_ref)
-
-
-def mttkrp_oriented_carry_pallas(enc: AltoEncoding, mode: int,
-                                 rows: jnp.ndarray, words: jnp.ndarray,
-                                 values: jnp.ndarray, factors,
-                                 block_m: int = DEFAULT_BLOCK_M,
-                                 r_block: int | None = None,
-                                 interpret: bool = True) -> jnp.ndarray:
-    """Scratch-carry oriented MTTKRP: sorted stream -> (I_n, R) directly.
-
-    Same input contract as `mttkrp_oriented_partials_pallas`, but the
-    result is the final row-reduced MTTKRP — there is no partials buffer
-    and callers must NOT run `ops.segment_merge` on this path. The grid
-    is (rank tiles, blocks) with the block axis innermost, so each rank
-    tile is one sequential scan and the carry scratch resets at its
-    first step.
-    """
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"nnz {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
-    R = factors[0].shape[1]
-    rb = r_block or R
-    if R % rb:
-        raise ValueError(f"rank {R} not a multiple of r_block {rb}")
-    I_n = enc.dims[mode]
-    dtype = factors[0].dtype
-    others = [f for m, f in enumerate(factors) if m != mode]
-
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda r, b: (b,)),           # rows
-        pl.BlockSpec((block_m, W), lambda r, b: (b, 0)),       # words
-        pl.BlockSpec((block_m,), lambda r, b: (b,)),           # values
-    ] + [
-        pl.BlockSpec((f.shape[0], rb), lambda r, b: (0, r)) for f in others
-    ] + [
-        pl.BlockSpec((I_n, rb), lambda r, b: (0, r)),          # zero init
-    ]
-    return pl.pallas_call(
-        functools.partial(_mttkrp_carry_kernel, enc, mode),
-        grid=(R // rb, n_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((I_n, rb), lambda r, b: (0, r)),
-        out_shape=jax.ShapeDtypeStruct((I_n, R), dtype),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((1, rb), dtype)],
-        input_output_aliases={3 + len(others): 0},
-        interpret=interpret,
-    )(rows, words, values, *others, jnp.zeros((I_n, R), dtype))
-
-
-def _phi_carry_kernel(enc: AltoEncoding, mode: int, eps: float,
-                      pre_pi: bool,
-                      rows_ref, words_ref, vals_ref, b_ref, *refs):
-    """Grid step: fused Φ update + carry scan, full rank, resident out."""
-    out_ref, crow_ref, cval_ref = refs[-3], refs[-2], refs[-1]
-    operand_refs = refs[:-4]                   # Π tile or other factors
-    rows = rows_ref[...]
-    vals = vals_ref[...]
-
-    if pre_pi:
-        krp = operand_refs[0][...]             # Π rows (block_m, R)
-    else:
-        coords = _decode(enc, words_ref[...])
-        krp = None
-        fi = 0
-        for m in range(enc.ndim):
-            if m == mode:
-                continue
-            gathered = jnp.take(operand_refs[fi][...], coords[m], axis=0)
-            krp = gathered if krp is None else krp * gathered
-            fi += 1
-
-    b_rows = jnp.take(b_ref[...], rows, axis=0)        # (block_m, R)
-    denom = jnp.maximum(jnp.sum(b_rows * krp, axis=-1), eps)
-    contrib = (vals / denom)[:, None] * krp
-
-    _carry_step(pl.program_id(0), pl.num_programs(0), rows, contrib,
-                out_ref, crow_ref, cval_ref)
-
-
-def phi_oriented_carry_pallas(enc: AltoEncoding, mode: int, eps: float,
-                              rows: jnp.ndarray, words: jnp.ndarray,
-                              values: jnp.ndarray, B: jnp.ndarray,
-                              factors=None, pi: jnp.ndarray | None = None,
-                              block_m: int = DEFAULT_BLOCK_M,
-                              interpret: bool = True) -> jnp.ndarray:
-    """Scratch-carry fused Φ: sorted stream -> (I_n, R) directly.
-
-    Same operand contract as `phi_oriented_partials_pallas` (pass exactly
-    one of ``pi``/``factors``; no rank tiling — the denominator needs the
-    full rank), but the result is the final row-reduced Φ with no
-    partials buffer and no merge pass.
-    """
-    pre_pi = pi is not None
-    if pre_pi == (factors is not None):
-        raise ValueError("pass exactly one of pi= / factors=")
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"nnz {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
-    I_n, R = B.shape
-
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # rows
-        pl.BlockSpec((block_m, W), lambda b: (b, 0)),          # words
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # values
-        pl.BlockSpec(B.shape, lambda b: (0, 0)),               # B resident
-    ]
-    args = [rows, words, values, B]
-    if pre_pi:
-        in_specs.append(pl.BlockSpec((block_m, R), lambda b: (b, 0)))
-        args.append(pi)
-    else:
-        others = [f for m, f in enumerate(factors) if m != mode]
-        in_specs += [pl.BlockSpec(f.shape, lambda b: (0, 0)) for f in others]
-        args += others
-    init_idx = len(args)
-    in_specs.append(pl.BlockSpec((I_n, R), lambda b: (0, 0)))  # zero init
-    args.append(jnp.zeros((I_n, R), B.dtype))
-
-    return pl.pallas_call(
-        functools.partial(_phi_carry_kernel, enc, mode, eps, pre_pi),
-        grid=(n_blocks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((I_n, R), lambda b: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((I_n, R), B.dtype),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((1, R), B.dtype)],
-        input_output_aliases={init_idx: 0},
-        interpret=interpret,
-    )(*args)
-
-
-# ---------------------------------------------------------------------------
-# Out-of-core chunk kernels: the carry scan sliced mid-stream
-# ---------------------------------------------------------------------------
-#
-# One chunk = a block_m-multiple slice of the padded sorted stream. The
-# kernel is the carry scan above with three contract changes (all through
-# `_carry_step`'s carry_in/final/carry_out hooks):
-#
-#   * the output accumulator arrives as an INPUT (`out_init`, aliased onto
-#     the output) holding the previous chunks' accumulation — chunk 0 gets
-#     zeros, later chunks get the running (I_n, R);
-#   * the carry scratch is seeded from the previous chunk's carry-out
-#     (row −1 + zeros for chunk 0) instead of reset at b == 0;
-#   * a non-final chunk suppresses the stream-closing flush and emits its
-#     open run as (cout_row, cout_val) outputs for the next chunk.
-#
-# Because chunk boundaries sit on block boundaries of the SAME padded
-# stream, every block performs the identical combined scatter-add in the
-# identical order — chunked-vs-in-core parity is bitwise, not approximate
-# (`tests/test_outofcore.py` pins it on adversarial run layouts).
-
-def _mttkrp_carry_chunk_kernel(enc: AltoEncoding, mode: int, final: bool,
-                               rows_ref, words_ref, vals_ref,
-                               cin_row_ref, cin_val_ref, *refs):
-    """Grid step: (rank tile r, chunk block b) -> resident (I_n, rb)."""
-    factor_refs = refs[:-6]
-    out_ref = refs[-5]
-    cout_row_ref, cout_val_ref = refs[-4], refs[-3]
-    crow_ref, cval_ref = refs[-2], refs[-1]
-    # refs[-6] is the out accumulator aliased onto out_ref — never read.
-    rows = rows_ref[...]
-    words = words_ref[...]
-    vals = vals_ref[...]
-    coords = _decode(enc, words)
-
-    krp = None
-    fi = 0
-    for m in range(enc.ndim):
-        if m == mode:
-            continue
-        gathered = jnp.take(factor_refs[fi][...], coords[m], axis=0)
-        krp = gathered if krp is None else krp * gathered
-        fi += 1
-    contrib = vals[:, None] * krp              # (block_m, rb)
-
-    _carry_step(pl.program_id(1), pl.num_programs(1), rows, contrib,
-                out_ref, crow_ref, cval_ref,
-                carry_in=(cin_row_ref, cin_val_ref), final=final,
-                carry_out=(cout_row_ref, cout_val_ref))
+    _carry_scan(block_m, pl.program_id(1) == 0, rows_ref, contrib, c_ref,
+                acc_ref, cin_row_ref, cin_val_ref, out_ref, cout_row_ref,
+                cout_val_ref, acc_sem)
 
 
 def mttkrp_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
@@ -547,7 +341,6 @@ def mttkrp_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
                                        carry_val: jnp.ndarray,
                                        block_m: int = DEFAULT_BLOCK_M,
                                        r_block: int | None = None,
-                                       final: bool = True,
                                        interpret: bool = True):
     """One chunk of the scratch-carry MTTKRP scan.
 
@@ -555,83 +348,97 @@ def mttkrp_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
     sorted stream; ``out`` is the running (I_n, R) accumulator (zeros
     for the first chunk); ``carry_row``/``carry_val`` — shapes (1,)
     int32 / (1, R) — are the previous chunk's open run (row −1 + zeros
-    for the first). ``final`` statically marks the stream's last chunk
-    (only there does the open run flush into ``out``). Returns the
-    updated ``(out, carry_row, carry_val)``.
+    for the first). Returns the updated ``(out, carry_row, carry_val)``.
+    The grid is (rank tiles, blocks) with the block axis innermost, so
+    each rank tile is one sequential scan.
     """
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"chunk {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
+    M = words.shape[0]
     R = factors[0].shape[1]
     rb = r_block or R
-    if R % rb:
-        raise ValueError(f"rank {R} not a multiple of r_block {rb}")
+    _check_stream(M, block_m, R, rb)
     I_n = enc.dims[mode]
-    dtype = factors[0].dtype
     others = [f for m, f in enumerate(factors) if m != mode]
-
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda r, b: (b,)),           # rows
-        pl.BlockSpec((block_m, W), lambda r, b: (b, 0)),       # words
-        pl.BlockSpec((block_m,), lambda r, b: (b,)),           # values
-        pl.BlockSpec((1,), lambda r, b: (0,)),                 # carry row in
-        pl.BlockSpec((1, rb), lambda r, b: (0, r)),            # carry val in
-    ] + [
-        pl.BlockSpec((f.shape[0], rb), lambda r, b: (0, r)) for f in others
-    ] + [
-        pl.BlockSpec((I_n, rb), lambda r, b: (0, r)),          # out accum in
-    ]
+    smem = pl.BlockSpec((block_m,), lambda r, b: (b,),
+                        memory_space=pltpu.SMEM)
+    tile = lambda r, b: (0, r)                                 # noqa: E731
+    in_specs = ([smem]                                          # rows
+                + [pl.BlockSpec((block_m,), lambda r, b: (b,))
+                   ] * enc.n_words                              # words
+                + [smem,                                        # values
+                   pl.BlockSpec(memory_space=pltpu.SMEM),       # carry row
+                   pl.BlockSpec((1, rb), tile)]                 # carry val
+                + [resident((f.shape[0], rb), tile) for f in others]
+                + [pl.BlockSpec(memory_space=pl.ANY)])          # accum
+    out_specs, out_shape = _carry_io(I_n, rb, R, out.dtype, tile)
+    acc_idx = len(in_specs) - 1
     return pl.pallas_call(
-        functools.partial(_mttkrp_carry_chunk_kernel, enc, mode, final),
-        grid=(R // rb, n_blocks),
+        functools.partial(_mttkrp_carry_kernel, enc, mode, block_m),
+        grid=(R // rb, M // block_m),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((I_n, rb), lambda r, b: (0, r)),
-                   pl.BlockSpec((1,), lambda r, b: (0,)),
-                   pl.BlockSpec((1, rb), lambda r, b: (0, r))],
-        out_shape=[jax.ShapeDtypeStruct((I_n, R), dtype),
-                   jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1, R), dtype)],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((1, rb), dtype)],
-        input_output_aliases={5 + len(others): 0},
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((block_m, rb), out.dtype)]
+        + stage_scratch(enc.ndim - 1, block_m)
+        + [pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={acc_idx: 0},
+        compiler_params=compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
-    )(rows, words, values, carry_row, carry_val, *others, out)
+    )(rows, *word_columns(words), values, carry_row, carry_val, *others,
+      out)
 
 
-def _phi_carry_chunk_kernel(enc: AltoEncoding, mode: int, eps: float,
-                            pre_pi: bool, final: bool,
-                            rows_ref, words_ref, vals_ref, b_ref,
-                            cin_row_ref, cin_val_ref, *refs):
-    """Grid step: fused Φ + chunked carry scan, full rank."""
-    operand_refs = refs[:-6]                   # Π tile or other factors
-    out_ref = refs[-5]
-    cout_row_ref, cout_val_ref = refs[-4], refs[-3]
-    crow_ref, cval_ref = refs[-2], refs[-1]
-    rows = rows_ref[...]
-    vals = vals_ref[...]
+def fresh_carry(I_n: int, R: int, dtype):
+    """Accumulator and carry of a scan that starts at the stream head."""
+    return (jnp.zeros((I_n, R), dtype), jnp.full((1,), -1, jnp.int32),
+            jnp.zeros((1, R), dtype))
 
+
+def mttkrp_oriented_carry_pallas(enc: AltoEncoding, mode: int,
+                                 rows: jnp.ndarray, words: jnp.ndarray,
+                                 values: jnp.ndarray, factors,
+                                 block_m: int = DEFAULT_BLOCK_M,
+                                 r_block: int | None = None,
+                                 interpret: bool = True) -> jnp.ndarray:
+    """Scratch-carry oriented MTTKRP: sorted stream -> (I_n, R) directly.
+
+    The one-chunk case of `mttkrp_oriented_carry_chunk_pallas`: the
+    result is the final row-reduced MTTKRP — there is no partials buffer
+    and callers must NOT run `ops.segment_merge` on this path.
+    """
+    R = factors[0].shape[1]
+    out, crow, cval = fresh_carry(enc.dims[mode], R, factors[0].dtype)
+    return mttkrp_oriented_carry_chunk_pallas(
+        enc, mode, rows, words, values, factors, out, crow, cval,
+        block_m=block_m, r_block=r_block, interpret=interpret)[0]
+
+
+def _phi_carry_kernel(enc: AltoEncoding, mode: int, eps: float,
+                      pre_pi: bool, block_m: int,
+                      rows_ref, vals_ref, b_ref, cin_row_ref, cin_val_ref,
+                      *refs):
+    """Grid step: fused Φ update + carry scan, full rank, resident out."""
+    n_other = enc.ndim - 1
     if pre_pi:
-        krp = operand_refs[0][...]             # Π rows (block_m, R)
+        (pi_ref, acc_ref, out_ref, cout_row_ref, cout_val_ref, c_ref,
+         acc_sem) = refs
     else:
-        coords = _decode(enc, words_ref[...])
-        krp = None
-        fi = 0
-        for m in range(enc.ndim):
-            if m == mode:
-                continue
-            gathered = jnp.take(operand_refs[fi][...], coords[m], axis=0)
-            krp = gathered if krp is None else krp * gathered
-            fi += 1
+        W = enc.n_words
+        word_refs, factor_refs = refs[:W], refs[W:W + n_other]
+        acc_ref = refs[W + n_other]
+        (out_ref, cout_row_ref, cout_val_ref, c_ref, cv_ref, cs_ref, sem,
+         acc_sem) = refs[W + n_other + 1:]
+        _stage_coords(enc, [m for m in range(enc.ndim) if m != mode],
+                      word_refs, cv_ref, cs_ref, sem)
 
-    b_rows = jnp.take(b_ref[...], rows, axis=0)        # (block_m, R)
-    denom = jnp.maximum(jnp.sum(b_rows * krp, axis=-1), eps)
-    contrib = (vals / denom)[:, None] * krp
+    def contrib(j):
+        krp = (pi_ref[pl.ds(j, 1), :] if pre_pi
+               else _krp_row(j, cs_ref, factor_refs))
+        b_row = b_ref[pl.ds(rows_ref[j], 1), :]
+        return phi_row(vals_ref, j, b_row, krp, eps)
 
-    _carry_step(pl.program_id(0), pl.num_programs(0), rows, contrib,
-                out_ref, crow_ref, cval_ref,
-                carry_in=(cin_row_ref, cin_val_ref), final=final,
-                carry_out=(cout_row_ref, cout_val_ref))
+    _carry_scan(block_m, pl.program_id(0) == 0, rows_ref, contrib, c_ref,
+                acc_ref, cin_row_ref, cin_val_ref, out_ref, cout_row_ref,
+                cout_val_ref, acc_sem)
 
 
 def phi_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
@@ -644,11 +451,10 @@ def phi_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
                                     factors=None,
                                     pi: jnp.ndarray | None = None,
                                     block_m: int = DEFAULT_BLOCK_M,
-                                    final: bool = True,
                                     interpret: bool = True):
     """One chunk of the scratch-carry fused Φ scan (full rank).
 
-    Operand contract as `phi_oriented_carry_pallas` (exactly one of
+    Operand contract as `phi_oriented_partials_pallas` (exactly one of
     ``pi``/``factors``; under ALTO-PRE ``pi`` holds THIS CHUNK's Π rows);
     chunk contract as `mttkrp_oriented_carry_chunk_pallas`. Returns the
     updated ``(out, carry_row, carry_val)``.
@@ -656,45 +462,49 @@ def phi_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
     pre_pi = pi is not None
     if pre_pi == (factors is not None):
         raise ValueError("pass exactly one of pi= / factors=")
-    M, W = words.shape
-    if M % block_m:
-        raise ValueError(f"chunk {M} not a multiple of block_m {block_m}")
-    n_blocks = M // block_m
+    M = words.shape[0]
     I_n, R = B.shape
-
-    in_specs = [
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # rows
-        pl.BlockSpec((block_m, W), lambda b: (b, 0)),          # words
-        pl.BlockSpec((block_m,), lambda b: (b,)),              # values
-        pl.BlockSpec(B.shape, lambda b: (0, 0)),               # B resident
-        pl.BlockSpec((1,), lambda b: (0,)),                    # carry row in
-        pl.BlockSpec((1, R), lambda b: (0, 0)),                # carry val in
-    ]
-    args = [rows, words, values, B, carry_row, carry_val]
-    if pre_pi:
-        in_specs.append(pl.BlockSpec((block_m, R), lambda b: (b, 0)))
-        args.append(pi)
-    else:
-        others = [f for m, f in enumerate(factors) if m != mode]
-        in_specs += [pl.BlockSpec(f.shape, lambda b: (0, 0)) for f in others]
-        args += others
-    init_idx = len(args)
-    in_specs.append(pl.BlockSpec((I_n, R), lambda b: (0, 0)))  # out accum
-    args.append(out)
-
+    _check_stream(M, block_m, R, R)
+    smem, word_specs, b_spec = _phi_stream_specs(
+        enc, pre_pi, block_m, B, lambda b: (b,), lambda b: (0, 0))
+    op_specs, op_args = _operand_specs(pre_pi, mode, block_m, R, factors,
+                                       pi, lambda b: (b, 0),
+                                       lambda b: (0, 0))
+    in_specs = ([smem, smem, b_spec,                            # rows, vals, B
+                 pl.BlockSpec(memory_space=pltpu.SMEM),         # carry row
+                 pl.BlockSpec((1, R), lambda b: (0, 0))]        # carry val
+                + word_specs + op_specs
+                + [pl.BlockSpec(memory_space=pl.ANY)])          # accum
+    out_specs, out_shape = _carry_io(I_n, R, R, out.dtype,
+                                     lambda b: (0, 0))
+    scratch = [pltpu.VMEM((block_m, R), out.dtype)]
+    if not pre_pi:
+        scratch += stage_scratch(enc.ndim - 1, block_m)
     return pl.pallas_call(
-        functools.partial(_phi_carry_chunk_kernel, enc, mode, eps, pre_pi,
-                          final),
-        grid=(n_blocks,),
+        functools.partial(_phi_carry_kernel, enc, mode, eps, pre_pi,
+                          block_m),
+        grid=(M // block_m,),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((I_n, R), lambda b: (0, 0)),
-                   pl.BlockSpec((1,), lambda b: (0,)),
-                   pl.BlockSpec((1, R), lambda b: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((I_n, R), B.dtype),
-                   jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1, R), B.dtype)],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((1, R), B.dtype)],
-        input_output_aliases={init_idx: 0},
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={len(in_specs) - 1: 0},
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
-    )(*args)
+    )(rows, values, B, carry_row, carry_val,
+      *([] if pre_pi else word_columns(words)), *op_args, out)
+
+
+def phi_oriented_carry_pallas(enc: AltoEncoding, mode: int, eps: float,
+                              rows: jnp.ndarray, words: jnp.ndarray,
+                              values: jnp.ndarray, B: jnp.ndarray,
+                              factors=None, pi: jnp.ndarray | None = None,
+                              block_m: int = DEFAULT_BLOCK_M,
+                              interpret: bool = True) -> jnp.ndarray:
+    """Scratch-carry fused Φ: sorted stream -> (I_n, R) directly (the
+    one-chunk case of `phi_oriented_carry_chunk_pallas`)."""
+    I_n, R = B.shape
+    out, crow, cval = fresh_carry(I_n, R, B.dtype)
+    return phi_oriented_carry_chunk_pallas(
+        enc, mode, eps, rows, words, values, B, out, crow, cval,
+        factors=factors, pi=pi, block_m=block_m, interpret=interpret)[0]

@@ -261,7 +261,7 @@ def delinearize(enc: AltoEncoding, words: jnp.ndarray,
             _, padded, _, _ = pad_sorted_stream(None, words, None, block_m)
             coords = _delin.delinearize_pallas(enc, padded, block_m=block_m,
                                                interpret=interp)
-            return coords[:words.shape[0]]
+            return coords[:, :words.shape[0]].T
         return jax.jit(run)
 
     fn = _cached_executable(("delin", enc, block_m, interp), build)
@@ -270,6 +270,7 @@ def delinearize(enc: AltoEncoding, words: jnp.ndarray,
 
 def mttkrp(at: AltoTensor, factors, mode: int,
            r_block: int | None = None,
+           block_m: int = _mttkrp.DEFAULT_BLOCK_M,
            interpret: bool | None = None) -> jnp.ndarray:
     """Recursive-traversal MTTKRP: Pallas partials kernel + pull reduction."""
     meta = at.meta
@@ -282,12 +283,14 @@ def mttkrp(at: AltoTensor, factors, mode: int,
         def run(words, values, part_start, factors):
             partials = _mttkrp.mttkrp_partials_pallas(
                 meta.enc, mode, meta.temp_rows[mode], words, values,
-                part_start, factors, r_block=rb, interpret=interp)
+                part_start, factors, r_block=rb, block_m=block_m,
+                interpret=interp)
             return pull_reduction(partials, part_start[:, mode],
                                   meta.dims[mode])
         return jax.jit(run)
 
-    fn = _cached_executable(("mttkrp_rec", meta, mode, rb, interp), build)
+    fn = _cached_executable(("mttkrp_rec", meta, mode, rb, block_m, interp),
+                            build)
     return fn(at.words, at.values, at.part_start, list(factors))
 
 
@@ -354,6 +357,7 @@ def mttkrp_oriented_carry(view: OrientedView, factors,
 def cpapr_phi(at: AltoTensor, B: jnp.ndarray, mode: int,
               factors=None, pi: jnp.ndarray | None = None,
               eps: float = 1e-10,
+              block_m: int = _mttkrp.DEFAULT_BLOCK_M,
               interpret: bool | None = None) -> jnp.ndarray:
     """Recursive-traversal fused Φ: Pallas partials kernel + pull reduction."""
     meta = at.meta
@@ -366,13 +370,14 @@ def cpapr_phi(at: AltoTensor, B: jnp.ndarray, mode: int,
         def run(words, values, part_start, B, factors, pi):
             partials = _phi.phi_partials_pallas(
                 meta.enc, mode, meta.temp_rows[mode], eps, words, values,
-                part_start, B, factors=factors, pi=pi, interpret=interp)
+                part_start, B, factors=factors, pi=pi, block_m=block_m,
+                interpret=interp)
             return pull_reduction(partials, part_start[:, mode],
                                   meta.dims[mode])
         return jax.jit(run)
 
     fn = _cached_executable(
-        ("phi_rec", meta, mode, eps, pre_pi, interp), build)
+        ("phi_rec", meta, mode, eps, pre_pi, block_m, interp), build)
     return fn(at.words, at.values, at.part_start, B,
               list(factors) if factors is not None else None, pi)
 
@@ -501,9 +506,7 @@ def mttkrp_oriented_chunked(view, factors, *, chunk_m: int,
     I_n = meta.dims[mode]
     dtype = factors[0].dtype
     factors = [jnp.asarray(f) for f in factors]
-    out = jnp.zeros((I_n, R), dtype)
-    crow = jnp.full((1,), -1, jnp.int32)
-    cval = jnp.zeros((1, R), dtype)
+    out, crow, cval = _oriented.fresh_carry(I_n, R, dtype)
 
     nxt = _stream.put_chunk(hs, *bounds[0])
     for i, (s, e) in enumerate(bounds):
@@ -512,18 +515,17 @@ def mttkrp_oriented_chunked(view, factors, *, chunk_m: int,
         if i + 1 < len(bounds):                # prefetch ahead of compute
             nxt = _stream.put_chunk(hs, *bounds[i + 1])
             _bump("prefetches")
-        final = i == len(bounds) - 1
 
-        def build(chunk_len=e - s, final=final):
+        def build(chunk_len=e - s):
             def run(rows, words, values, factors, out, crow, cval):
                 return _oriented.mttkrp_oriented_carry_chunk_pallas(
                     meta.enc, mode, rows, words, values, factors,
                     out, crow, cval, block_m=block_m, r_block=rb,
-                    final=final, interpret=interp)
+                    interpret=interp)
             return jax.jit(run)
 
         fn = _cached_executable(
-            ("mttkrp_chunk", meta, mode, e - s, block_m, rb, final, interp),
+            ("mttkrp_chunk", meta, mode, e - s, block_m, rb, interp),
             build)
         out, crow, cval = fn(*cur, factors, out, crow, cval)
         _bump("chunks")
@@ -597,9 +599,7 @@ def cpapr_phi_oriented_chunked(view, B: jnp.ndarray, factors, *,
     I_n, R = B.shape
     B = jnp.asarray(B)
     factors = [jnp.asarray(f) for f in factors]
-    out = jnp.zeros((I_n, R), B.dtype)
-    crow = jnp.full((1,), -1, jnp.int32)
-    cval = jnp.zeros((1, R), B.dtype)
+    out, crow, cval = _oriented.fresh_carry(I_n, R, B.dtype)
 
     nxt = _stream.put_chunk(hs, *bounds[0])
     for i, (s, e) in enumerate(bounds):
@@ -608,9 +608,8 @@ def cpapr_phi_oriented_chunked(view, B: jnp.ndarray, factors, *,
         if i + 1 < len(bounds):
             nxt = _stream.put_chunk(hs, *bounds[i + 1])
             _bump("prefetches")
-        final = i == len(bounds) - 1
 
-        def build(chunk_len=e - s, final=final):
+        def build(chunk_len=e - s):
             def run(rows, words, values, B, factors, out, crow, cval):
                 if pre:
                     coords = _delin_jnp(meta.enc, words)
@@ -618,16 +617,16 @@ def cpapr_phi_oriented_chunked(view, B: jnp.ndarray, factors, *,
                     return _oriented.phi_oriented_carry_chunk_pallas(
                         meta.enc, mode, eps, rows, words, values, B,
                         out, crow, cval, pi=pi, block_m=block_m,
-                        final=final, interpret=interp)
+                        interpret=interp)
                 return _oriented.phi_oriented_carry_chunk_pallas(
                     meta.enc, mode, eps, rows, words, values, B,
                     out, crow, cval, factors=factors, block_m=block_m,
-                    final=final, interpret=interp)
+                    interpret=interp)
             return jax.jit(run)
 
         fn = _cached_executable(
-            ("phi_chunk", meta, mode, eps, pre, e - s, block_m, final,
-             interp), build)
+            ("phi_chunk", meta, mode, eps, pre, e - s, block_m, interp),
+            build)
         out, crow, cval = fn(*cur, B, factors, out, crow, cval)
         _bump("chunks")
     return out

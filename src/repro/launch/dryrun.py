@@ -14,8 +14,10 @@ Per cell this produces:
 Results land in experiments/dryrun/<arch>_<shape>_<mesh>.json.
 """
 # The VERY FIRST lines — before ANY other import, jax locks the device
-# count on first init:
+# count on first init. The dry run compiles for 512 fake host devices and
+# never takes a chip:
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
                            " --xla_force_host_platform_device_count=512")
 

@@ -40,9 +40,11 @@ survives any request's failure; every failure mode maps to a structured
 * transient faults (I/O blips, allocator RESOURCE_EXHAUSTED —
   `faults.is_transient`) are retried with exponential backoff;
 * plan failures walk the degradation ladder (`health.degrade_plan`):
-  streaming OOM halves ``chunk_m``, a Pallas kernel failure drops to
-  the reference backend, and a stored plan that fails at dispatch is
-  evicted from the autotune store and replaced by the heuristic plan;
+  streaming OOM halves ``chunk_m``, and a stored plan that fails at
+  dispatch is evicted from the autotune store and replaced by the
+  heuristic plan. A Pallas kernel that fails under the heuristic plan
+  is a defect, returned as the request's error — never re-served on
+  the reference backend;
 * a bucket that still fails is *bisected*: each member re-runs solo,
   and an offender that fails alone too is quarantined with a
   structured error while its bucket-mates' results are unaffected;
@@ -63,6 +65,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import caches
 from repro.core import alto, batched, faults, shapeclass
 from repro.core import autotune as autotune_mod
 from repro.core import cpals as cpals_mod
@@ -108,8 +111,8 @@ class CpdResponse:
     # deadline-expired request gets the reason here (its ``result`` may
     # still carry the last good, rolled-back iterate — degraded but
     # finite — or be None when nothing was computed). ``degraded`` marks
-    # results served through a ladder rung (reference backend, halved
-    # chunks, evicted store plan); ``retries`` counts transient-fault
+    # results served through a ladder rung (halved chunks, evicted
+    # store plan); ``retries`` counts transient-fault
     # re-attempts absorbed on this request's behalf.
     error: str | None = None
     degraded: bool = False
@@ -383,11 +386,11 @@ class CpdService:
         Rungs, in order, per failure: (1) transient fault
         (`faults.is_transient`) → retry with exponential backoff, up to
         ``max_retries``; (2) `health.degrade_plan` → swap the class plan
-        (halved ``chunk_m`` on streaming OOM, reference backend on a
-        Pallas failure) and re-run; (3) a stored plan failing at
-        dispatch → evict it from the autotune store, rebuild the
-        heuristic plan (``tune="off"``), re-run once. ``run`` must read
-        the current class plan each attempt so rung swaps take effect.
+        (halved ``chunk_m`` on streaming OOM) and re-run; (3) a stored
+        plan failing at dispatch → evict it from the autotune store,
+        rebuild the heuristic plan (``tune="off"``), re-run once. ``run``
+        must read the current class plan each attempt so rung swaps take
+        effect.
         """
         retries = 0
         degraded = False
@@ -771,6 +774,7 @@ def main(argv=None):
                          "--tune search (default: the engine's 25%% "
                          "of the feasible space)")
     args = ap.parse_args(argv)
+    caches.use_compile_cache()
 
     svc = CpdService(args.rank, args.algorithm, capacity=args.capacity,
                      n_iters=args.iters, tune=args.tune,

@@ -66,8 +66,16 @@ class SparseTensor:
 
     def deduplicate(self) -> "SparseTensor":
         """Sum values of duplicate coordinates (canonicalisation)."""
-        order = np.lexsort(tuple(self.coords[:, n]
-                                 for n in range(self.ndim - 1, -1, -1)))
+        if float(np.prod([float(d) for d in self.dims])) < 2.0 ** 63:
+            # Row-major linear index: one stable int64 argsort orders the
+            # entries exactly as the lexsort below, several times faster.
+            key = np.zeros(self.nnz, np.int64)
+            for n, I in enumerate(self.dims):
+                key = key * I + self.coords[:, n]
+            order = np.argsort(key, kind="stable")
+        else:
+            order = np.lexsort(tuple(self.coords[:, n]
+                                     for n in range(self.ndim - 1, -1, -1)))
         c = self.coords[order]
         v = self.values[order]
         if c.shape[0] == 0:

@@ -191,17 +191,25 @@ def test_build_device_traces_once_per_meta():
 
 
 def test_ingest_cores_have_zero_host_callbacks():
-    """The jitted build/view cores must be pure device programs — no
-    pure_callback/io_callback/debug.callback primitives in the jaxpr."""
+    """The jitted build/fiber/view cores must be pure device programs —
+    no pure_callback/io_callback/debug.callback primitives in the jaxpr."""
     x = _random_tensor((40, 30, 20), 200, seed=11)
     enc = E.make_encoding(x.dims)
-    build_fn = alto._build_device_fn(enc, 4, x.nnz, True, jnp.float32)
+    build_fn = alto._build_device_fn(enc, 4, x.nnz, jnp.float32)
     jaxpr = jax.make_jaxpr(build_fn)(jnp.asarray(x.coords),
                                      jnp.asarray(x.values))
     assert "callback" not in str(jaxpr)
     d = alto.build_device(x, n_partitions=4)
-    view_fn = alto._view_device_fn(enc, 0, d.words.shape[0], jnp.float32)
-    jaxpr = jax.make_jaxpr(view_fn)(d.words, d.values)
+    Mp, W = d.words.shape
+    fiber_fn = alto._fiber_count_fn(x.nnz, Mp, W)
+    jaxpr = jax.make_jaxpr(fiber_fn)(d.words,
+                                     jnp.asarray(~enc.mode_masks()[0]))
+    assert "callback" not in str(jaxpr)
+    rows = alto._view_rows_fn(enc, 0, Mp)
+    jaxpr = jax.make_jaxpr(rows)(d.words)
+    assert "callback" not in str(jaxpr)
+    view_fn = alto._view_sort_fn(Mp, W, jnp.float32)
+    jaxpr = jax.make_jaxpr(view_fn)(rows(d.words), d.words, d.values)
     assert "callback" not in str(jaxpr)
 
 
@@ -210,12 +218,12 @@ def test_build_device_core_runs_under_jit():
     to end — e.g. regeneration inside a larger traced program)."""
     x = _random_tensor((16, 12, 9), 90, seed=13)
     enc = E.make_encoding(x.dims)
-    fn = alto._build_device_fn(enc, 4, x.nnz, True, jnp.float32)
+    fn = alto._build_device_fn(enc, 4, x.nnz, jnp.float32)
 
     @jax.jit
     def outer(coords, values):
-        words, vals, ps, pe, fibers = fn(coords, values)
-        return words, vals, ps, pe, fibers
+        words, vals, ps, pe = fn(coords, values)
+        return words, vals, ps, pe
 
     words, *_ = outer(jnp.asarray(x.coords), jnp.asarray(x.values))
     h = alto.build(x, n_partitions=4)

@@ -245,8 +245,9 @@ def test_pipeline_params_roundtrip():
 
 def test_mesh_plan_resolution():
     """Mesh plans force the oriented *family* everywhere (one-hot merge or
-    scratch carry, both shardable) and divide the VMEM budget per shard
-    (never larger tiles than the single-device plan).
+    scratch carry, both shardable) and size shard-local tiles against each
+    device's own VMEM: every mode's tiling is the single-device oriented
+    plan's.
     """
     x = synthetic.blocked_tensor((64, 48, 32), 20_000, seed=0)
     at = alto.build(x, n_partitions=8)
@@ -256,8 +257,10 @@ def test_mesh_plan_resolution():
     assert all(heuristics.is_oriented(mp.traversal) for mp in meshed.modes)
     assert meshed.n_shards == 1 and meshed.mesh_axis == "data"
     assert single.mesh is None and single.n_shards == 1
-    for mp_s, mp_m in zip(single.modes, meshed.modes):
-        assert mp_m.block_m <= max(mp_s.block_m, plan_mod.MIN_BLOCK_M)
+    pre = meshed.pi_policy is heuristics.PiPolicy.PRE
+    for n, mp_m in enumerate(meshed.modes):
+        assert mp_m == plan_mod.static_mode_plan(
+            at.meta, n, 16, force_oriented=True, pre_pi=pre)
 
 
 def test_mesh_plan_hashing_and_caching():

@@ -11,6 +11,22 @@ from repro.core.heuristics import (BUFFERED_ACCUM_COST, HIGH_REUSE,
 from repro.sparse import synthetic
 
 
+def _tile(rows, cols, db=4):
+    """VMEM bytes of a (rows, cols) array in (8·4/db, 128) tiles."""
+    sub = 32 // db
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * db
+
+
+def _words(meta, bm, n_staged):
+    """Double-buffered index-word blocks + the coordinate staging tile."""
+    return 2 * meta.enc.n_words * bm * 4 + _tile(n_staged, bm)
+
+
+def _others(meta, mode, cols, db=4):
+    return sum(_tile(I, cols, db) for m, I in enumerate(meta.dims)
+               if m != mode)
+
+
 def _meta_with_reuse(reuse_per_mode):
     x = synthetic.uniform_tensor((16, 12, 8)[:len(reuse_per_mode)],
                                  200, seed=0)
@@ -149,17 +165,14 @@ class TestPhiVmemFootprint:
     def test_phi_oriented_exact_bytes_otf(self):
         meta = self._meta()
         mode, bm, R, db = 1, 64, 8, 4
-        W = meta.enc.n_words
-        want = (bm * W * 4                      # words tile
-                + bm * 4                        # rows tile (int32)
-                + bm * db                       # values tile
-                + bm * bm * db                  # segment one-hot
-                + meta.dims[mode] * R * db      # RESIDENT full-rank B
-                + bm * R * db                   # gathered B block rows
-                + 2 * bm * R * db               # krp + contrib
-                + bm * R * db                   # segment-sum output tile
-                + sum(I for m, I in enumerate(meta.dims)
-                      if m != mode) * R * db)   # resident other factors
+        N = meta.enc.ndim
+        want = (_words(meta, bm, N - 1)         # words + staging
+                + 2 * bm * 4                    # segment-id blocks
+                + bm * bm * (4 + db)            # one-hot + its iota
+                + _tile(meta.dims[mode], R)     # RESIDENT full-rank B
+                + _tile(bm, R)                  # contribution tile
+                + 2 * _tile(bm, R)              # segment-sum output
+                + _others(meta, mode, R))       # resident other factors
         got = plan_mod.phi_oriented_vmem_bytes(meta, mode, bm, R, db)
         assert got == want
 
@@ -170,26 +183,20 @@ class TestPhiVmemFootprint:
                                                pre_pi=False)
         pre = plan_mod.phi_oriented_vmem_bytes(meta, mode, bm, R, db,
                                                pre_pi=True)
-        others = sum(I for m, I in enumerate(meta.dims) if m != mode)
-        # PRE swaps the resident factors for a (block_m, R) Π tile
-        assert otf - pre == (others - bm) * R * db
+        # PRE swaps the words, staging and resident factors for a
+        # double-buffered (block_m, R) Π tile
+        assert otf - pre == (_words(meta, bm, meta.enc.ndim - 1)
+                             + _others(meta, mode, R) - 2 * _tile(bm, R))
 
     def test_phi_recursive_exact_bytes_otf(self):
         meta = self._meta(L=4)
         mode, R, db = 2, 8, 4
-        L = meta.n_partitions
-        chunk = -(-max(meta.nnz, L) // L)
+        bm = plan_mod.MIN_BLOCK_M
         T = meta.temp_rows[mode]
-        W = meta.enc.n_words
-        want = (chunk * W * 4                   # words tile
-                + chunk * db                    # values tile
-                + chunk * T * db                # Temp one-hot
-                + meta.dims[mode] * R * db      # RESIDENT full-rank B
-                + chunk * R * db                # gathered B rows
-                + 2 * chunk * R * db            # krp + contrib
-                + T * R * db                    # partition Temp output
-                + sum(I for m, I in enumerate(meta.dims)
-                      if m != mode) * R * db)   # resident other factors
+        want = (_words(meta, bm, meta.enc.ndim)  # words + all-mode staging
+                + 2 * _tile(T, R)               # partition Temp output
+                + _tile(meta.dims[mode], R)     # RESIDENT full-rank B
+                + _others(meta, mode, R))       # resident other factors
         got = plan_mod.phi_recursive_vmem_bytes(meta, mode, R, db)
         assert got == want
 
@@ -226,7 +233,7 @@ class TestPhiVmemFootprint:
         """When the resident-B term alone overflows the budget at every
         block size, Φ spills regardless — the vacuous constraint must
         not drag the MTTKRP kernel's block down to the minimum."""
-        meta = self._meta(dims=(4096, 24, 16), nnz=3000)
+        meta = self._meta(dims=(65536, 24, 16), nnz=3000)
         R = 64
         # budget below Φ's floor but roomy for MTTKRP tiles
         budget = plan_mod.phi_oriented_vmem_bytes(
@@ -280,33 +287,23 @@ class TestCarryVmemFootprint:
     def test_carry_exact_bytes(self):
         meta = self._meta()
         mode, bm, rb, db = 1, 64, 8, 4
-        W = meta.enc.n_words
-        want = (bm * W * 4                      # words tile
-                + bm * 4                        # rows tile (int32)
-                + bm * db                       # values tile
-                + 3 * bm * rb * db              # krp + contrib + seg sums
-                + meta.dims[mode] * rb * db     # RESIDENT output tile
-                + rb * db                       # carry scratch row
-                + sum(I for m, I in enumerate(meta.dims)
-                      if m != mode) * rb * db)  # resident other factors
+        want = (_words(meta, bm, meta.enc.ndim - 1)  # words + staging
+                + _tile(bm, rb)                 # contribution tile
+                + 2 * _tile(meta.dims[mode], rb)  # RESIDENT output tile
+                + 4 * _tile(1, rb)              # carry in/out blocks
+                + _others(meta, mode, rb))      # resident other factors
         got = plan_mod.oriented_carry_vmem_bytes(meta, mode, bm, rb, db)
         assert got == want
 
     def test_phi_carry_exact_bytes_otf(self):
         meta = self._meta()
         mode, bm, R, db = 0, 32, 8, 4
-        W = meta.enc.n_words
-        want = (bm * W * 4                      # words tile
-                + bm * 4                        # rows tile
-                + bm * db                       # values tile
-                + meta.dims[mode] * R * db      # RESIDENT full-rank B
-                + bm * R * db                   # gathered B block rows
-                + 2 * bm * R * db               # krp + contrib
-                + bm * R * db                   # segment sums
-                + meta.dims[mode] * R * db      # RESIDENT output block
-                + R * db                        # carry scratch row
-                + sum(I for m, I in enumerate(meta.dims)
-                      if m != mode) * R * db)   # resident other factors
+        want = (_words(meta, bm, meta.enc.ndim - 1)  # words + staging
+                + _tile(meta.dims[mode], R)     # RESIDENT full-rank B
+                + _tile(bm, R)                  # contribution tile
+                + 2 * _tile(meta.dims[mode], R)  # RESIDENT output block
+                + 4 * _tile(1, R)               # carry in/out blocks
+                + _others(meta, mode, R))       # resident other factors
         got = plan_mod.phi_oriented_carry_vmem_bytes(meta, mode, bm, R, db)
         assert got == want
 
@@ -317,8 +314,8 @@ class TestCarryVmemFootprint:
                                                      pre_pi=False)
         pre = plan_mod.phi_oriented_carry_vmem_bytes(meta, mode, bm, R, db,
                                                      pre_pi=True)
-        others = sum(I for m, I in enumerate(meta.dims) if m != mode)
-        assert otf - pre == (others - bm) * R * db
+        assert otf - pre == (_words(meta, bm, meta.enc.ndim - 1)
+                             + _others(meta, mode, R) - 2 * _tile(bm, R))
 
     def test_no_onehot_term(self):
         """Doubling block_m must grow the carry footprint linearly (the

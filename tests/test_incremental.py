@@ -245,7 +245,7 @@ def test_merge_core_has_zero_host_callbacks():
         enc = E.make_encoding(dims)
         fn = ingest._merge_device_fn(
             at.meta.enc, enc, 4, at.nnz, at.words.shape[0],
-            coords.shape[0], "last", True, jnp.float32, "coords")
+            coords.shape[0], "last", jnp.float32, "coords")
         jaxpr = jax.make_jaxpr(fn)(at.words, at.values,
                                    jnp.asarray(coords),
                                    jnp.asarray(values))

@@ -265,15 +265,17 @@ def test_modeled_chunk_count_matches_executed_grid():
 def _tensor_and_meta(seed=0, scale=4):
     rng = np.random.default_rng(seed)
     counts = rng.integers(0, scale * 2, size=DIMS[0])
-    counts[3] = scale * BM
+    # long enough for three chunks of two minimum blocks each
+    counts[3] = scale * BM + 6 * plan_mod.MIN_BLOCK_M
     x = _stream_tensor(counts, seed, count_data=True)
     return alto.build(x, n_partitions=2)
 
 
 def _streaming_plan(at, R, n_chunks_min=3):
     """A streaming plan with a genuinely multi-chunk grid: vmem_limit=0
-    makes every tiling choice advisory-minimal (block_m == MIN == 8), so
-    the chunk alignment is 8 and a small budget yields several chunks."""
+    makes every tiling choice advisory-minimal (block_m == MIN_BLOCK_M),
+    so the chunk alignment is MIN_BLOCK_M and a small budget yields
+    several chunks."""
     meta = at.meta
     resident = plan_mod.streaming_resident_bytes(meta, R)
     elem = plan_mod.stream_elem_bytes(meta)

@@ -15,7 +15,7 @@ no crash, no poisoned bucket-mates, no torn on-disk state:
   good iterate (solo and per-tenant in a bucket) and change NOTHING on
   finite inputs — guarded runs stay bitwise identical to unguarded;
 * the service walks the recovery ladders: transient retry with backoff,
-  plan degradation (OOM -> halve chunk_m, Pallas -> reference), stored
+  plan degradation (OOM -> halve chunk_m; kernel failures surface), stored
   plan eviction, bucket bisection -> solo -> quarantine; deadlines and
   the deadline-aware flush bound tail latency; the background worker
   loop survives a 16-thread submit/delta/shutdown stress.
@@ -264,15 +264,21 @@ class TestChunkFaults:
         assert last.streaming.chunk_m == align
 
     def test_degrade_plan_backend_rung_and_exhaustion(self):
+        """A kernel failure has no softer plan: the Pallas plan is never
+        swapped for the reference backend, and a served request gets
+        the failure as its structured error, not a degraded answer."""
         at = alto.build(_tensor(seed=6), n_partitions=2)
         plan = plan_mod.make_plan(at.meta, RANK, backend="pallas")
-        soft, why = health.degrade_plan(
+        out, why = health.degrade_plan(
             plan, faults.InjectedDispatchError("kernel build failed"))
-        assert soft.backend == "reference" and "reference" in why
-        # the reference in-core plan has no softer rung
-        out, why2 = health.degrade_plan(
-            soft, faults.InjectedDispatchError("again"))
-        assert out is None and why2 is None
+        assert out is None and why is None
+        svc = _service(capacity=1, backend="pallas")
+        faults.arm("plan.dispatch", times=2)      # the bucket, then solo
+        rid = svc.submit(_tensor(seed=6))
+        r = {r.request_id: r for r in svc.process()}[rid]
+        assert not r.ok and not r.degraded
+        assert "injected dispatch failure" in r.error
+        assert svc.stats()["degraded_dispatches"] == 0
 
 
 # ---------------------------------------------------------------------------
