@@ -85,7 +85,11 @@ def _sweep(plan, at: AltoTensor, views, factors, lam, gram_fn=None):
     row-sharded and psum-combined. MTTKRP placement needs no hook — a
     mesh-bearing plan already routes it through the sharded merge.
     """
-    gram = gram_fn if gram_fn is not None else (lambda A: A.T @ A)
+    # f32 at full precision: the TPU's default matmul rounds its inputs
+    # to bf16, three significant digits of every factor.
+    hi = jax.lax.Precision.HIGHEST
+    gram = gram_fn if gram_fn is not None else (
+        lambda A: jnp.matmul(A.T, A, precision=hi))
     N = len(factors)
     grams = [gram(A) for A in factors]
     M = None
@@ -96,7 +100,7 @@ def _sweep(plan, at: AltoTensor, views, factors, lam, gram_fn=None):
                 continue
             V = grams[m] if V is None else V * grams[m]
         M = mttkrp_adaptive(at, views, factors, n, plan=plan)  # (I_n, R)
-        A = M @ jnp.linalg.pinv(V)
+        A = jnp.matmul(M, jnp.linalg.pinv(V), precision=hi)
         lam = jnp.linalg.norm(A, axis=0)
         lam = jnp.where(lam > 0, lam, 1.0)
         A = A / lam[None, :]

@@ -69,6 +69,7 @@ from repro.core import faults
 from repro.core import heuristics
 from repro.core import mttkrp as core_mttkrp
 from repro.core.alto import AltoMeta, AltoTensor, OrientedView, delinearize
+from repro.kernels.mttkrp import DEFAULT_BLOCK_M
 
 # VMEM the kernels' blocks may use per grid step. A v5e core has 128 MiB
 # of VMEM; the kernels raise Mosaic's scoped limit to
@@ -79,8 +80,9 @@ VMEM_BYTES = 96 * 1024 * 1024
 
 # Nonzero blocks are 1-D tiles of the lane-dense stream arrays, which XLA
 # lays out in 1024-element tiles on the chip: block_m is a multiple of
-# 1024. The one-hot kernel's (block_m, block_m) operand caps it above.
-MIN_BLOCK_M = 1024
+# 1024, and the recursive kernels always run this smallest block. The
+# one-hot kernel's (block_m, block_m) operand caps it above.
+MIN_BLOCK_M = DEFAULT_BLOCK_M
 MAX_BLOCK_M = 2048
 # A rank tile is the whole rank or a multiple of the 128-lane vreg width.
 LANES = 128
@@ -183,8 +185,7 @@ def _onehot_bytes(block_m: int, dtype_bytes: int) -> int:
 
 
 def recursive_vmem_bytes(meta: AltoMeta, mode: int, r_block: int,
-                         dtype_bytes: int = 4,
-                         block_m: int = MIN_BLOCK_M) -> int:
+                         dtype_bytes: int = 4) -> int:
     """Per-grid-step VMEM of the recursive (partition Temp) kernel.
 
     Word blocks plus the staging tile of all N coordinates, the
@@ -193,7 +194,7 @@ def recursive_vmem_bytes(meta: AltoMeta, mode: int, r_block: int,
     in SMEM and are not counted.
     """
     T = meta.temp_rows[mode]
-    return (_stream_bytes(meta, block_m, meta.enc.ndim)
+    return (_stream_bytes(meta, MIN_BLOCK_M, meta.enc.ndim)
             + 2 * _tile_bytes(T, r_block, dtype_bytes)
             + _factor_bytes(meta, mode, r_block, dtype_bytes))
 
@@ -284,23 +285,22 @@ def phi_oriented_carry_vmem_bytes(meta: AltoMeta, mode: int, block_m: int,
 
 def phi_recursive_vmem_bytes(meta: AltoMeta, mode: int, rank: int,
                              dtype_bytes: int = 4,
-                             pre_pi: bool = False,
-                             block_m: int = MIN_BLOCK_M) -> int:
+                             pre_pi: bool = False) -> int:
     """Per-grid-step VMEM of the *recursive fused Φ* kernel — full rank.
 
     Word blocks and the staging of all N coordinates (the target mode
     indexes Temp and B), the double-buffered ``(T, R)`` partition Temp,
     resident B, and the Π tile or resident other factors. Nothing here
-    is tunable but block_m (Φ runs full rank), so this footprint is
+    is tunable (Φ runs full rank, at the fixed block), so this footprint is
     advisory — it is reported in the plan and used by the per-shard
     budget checks.
     """
     T = meta.temp_rows[mode]
     if pre_pi:
-        operands = 2 * _tile_bytes(block_m, rank, dtype_bytes)
+        operands = 2 * _tile_bytes(MIN_BLOCK_M, rank, dtype_bytes)
     else:
         operands = _factor_bytes(meta, mode, rank, dtype_bytes)
-    return (_stream_bytes(meta, block_m, meta.enc.ndim)
+    return (_stream_bytes(meta, MIN_BLOCK_M, meta.enc.ndim)
             + 2 * _tile_bytes(T, rank, dtype_bytes)
             + _tile_bytes(meta.dims[mode], rank, dtype_bytes)
             + operands)
@@ -582,10 +582,9 @@ def _mode_plan(meta: AltoMeta, mode: int, rank: int,
                dtype_bytes: int, pre_pi: bool) -> ModePlan:
     """Assemble a ModePlan with both kernel footprints filled in."""
     if traversal is heuristics.Traversal.RECURSIVE:
-        vm = recursive_vmem_bytes(meta, mode, r_block, dtype_bytes,
-                                  block_m=block_m)
+        vm = recursive_vmem_bytes(meta, mode, r_block, dtype_bytes)
         phi_vm = phi_recursive_vmem_bytes(meta, mode, rank, dtype_bytes,
-                                          pre_pi=pre_pi, block_m=block_m)
+                                          pre_pi=pre_pi)
     else:
         vm = _mttkrp_vmem_model(traversal)(meta, mode, block_m, r_block,
                                            dtype_bytes)
@@ -637,7 +636,7 @@ def static_mode_plan(meta: AltoMeta, mode: int, rank: int, *,
         rb = choose_rank_block_oriented(meta, mode, rank, dtype_bytes,
                                         vmem_limit)
     if traversal is heuristics.Traversal.RECURSIVE:
-        bm = MIN_BLOCK_M    # larger blocks only mean fewer grid steps
+        bm = MIN_BLOCK_M    # the recursive kernels' fixed block
     else:
         bm = choose_block_m(meta, mode, rb, dtype_bytes, vmem_limit,
                             rank=rank, pre_pi=pre_pi, traversal=traversal)
@@ -943,7 +942,7 @@ def execute_mttkrp(plan: ExecutionPlan, at: AltoTensor,
                                        r_block=mp.r_block,
                                        interpret=plan.interpret)
         return ops.mttkrp(at, factors, mode, r_block=mp.r_block,
-                          block_m=mp.block_m, interpret=plan.interpret)
+                          interpret=plan.interpret)
     # reference backend: both oriented variants are the same sorted
     # segment_sum — the carry is a kernel-level distinction.
     if oriented:
@@ -1003,7 +1002,7 @@ def execute_phi(plan: ExecutionPlan, at: AltoTensor,
                                           eps=eps, block_m=mp.block_m,
                                           interpret=plan.interpret)
         return ops.cpapr_phi(at, B, mode, factors=factors, pi=pi, eps=eps,
-                             block_m=mp.block_m, interpret=plan.interpret)
+                             interpret=plan.interpret)
     # reference backend: pure-jnp traversals. Under ALTO-PRE the index
     # decode is dead work (the Pallas kernel skips it too): the oriented
     # view already materializes the target rows, so only the OTF path —

@@ -149,7 +149,8 @@ def local_phi(plan: plan_mod.ExecutionPlan, mode: int, eps: float, rows,
 def local_gram(A_shard: jnp.ndarray) -> jnp.ndarray:
     """One device's Gram contribution over its row slice: AᵀA is a sum of
     rank-1 outer products, so row shards combine by plain addition."""
-    return A_shard.T @ A_shard
+    return jnp.matmul(A_shard.T, A_shard,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------

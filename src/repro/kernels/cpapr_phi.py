@@ -1,6 +1,6 @@
 """Pallas TPU kernel: fused CP-APR Φ model update (paper Alg. 5).
 
-Per element of each ``block_m`` block of a balanced ALTO partition the
+Per element of each `DEFAULT_BLOCK_M` block of a balanced ALTO partition the
 kernel fuses, entirely in VMEM: delinearization → Khatri-Rao row
 formation (ALTO-OTF) or Π row load (ALTO-PRE) → B-row load → denominator
 dot → elementwise Poisson update → read-modify-write of the partition's
@@ -35,7 +35,7 @@ def phi_row(vals_ref, j, b_row, krp, eps):
 
 
 def _phi_partial_kernel(enc: AltoEncoding, mode: int, eps: float,
-                        pre_pi: bool, block_m: int, start_ref, *refs):
+                        pre_pi: bool, start_ref, *refs):
     W, n_other = enc.n_words, enc.ndim - 1
     word_refs, vals_ref, b_ref = refs[:W], refs[W], refs[W + 1]
     n_ops = 1 if pre_pi else n_other
@@ -60,14 +60,13 @@ def _phi_partial_kernel(enc: AltoEncoding, mode: int, eps: float,
         out_ref[0, pl.ds(row - start, 1), :] += contrib
         return state
 
-    _for_each(block_m, body, 0)
+    _for_each(DEFAULT_BLOCK_M, body, 0)
 
 
 def phi_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
                         eps: float, words: jnp.ndarray, values: jnp.ndarray,
                         part_start: jnp.ndarray, B: jnp.ndarray,
                         factors=None, pi: jnp.ndarray | None = None,
-                        block_m: int = DEFAULT_BLOCK_M,
                         interpret: bool = True) -> jnp.ndarray:
     """Per-partition Φ partials: (L, temp_rows, R).
 
@@ -76,6 +75,7 @@ def phi_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
     pre_pi = pi is not None
     if pre_pi == (factors is not None):
         raise ValueError("pass exactly one of pi= / factors=")
+    block_m = DEFAULT_BLOCK_M
     L = part_start.shape[0]
     R = B.shape[1]
     cols, values, pi, chunk = pad_partitions(L, block_m, word_columns(words),
@@ -101,8 +101,7 @@ def phi_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
         args += others
 
     return pl.pallas_call(
-        functools.partial(_phi_partial_kernel, enc, mode, eps, pre_pi,
-                          block_m),
+        functools.partial(_phi_partial_kernel, enc, mode, eps, pre_pi),
         grid=(L, nbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, temp_rows, R), lambda l, b: (l, 0, 0)),

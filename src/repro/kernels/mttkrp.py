@@ -1,7 +1,8 @@
 """Pallas TPU kernel: partitioned MTTKRP over ALTO tensors (paper Alg. 4).
 
-The grid walks each balanced ALTO partition in ``block_m``-element blocks
-(and one rank tile at a time) and accumulates that partition's dense
+The grid walks each balanced ALTO partition in blocks of
+`DEFAULT_BLOCK_M` elements (and one rank tile at a time) and
+accumulates that partition's dense
 ``Temp`` buffer — the local buffer of the paper's recursive traversal —
 in a VMEM-resident output block. The pull-based reduction (Alg. 4 lines
 14-18) merges partials outside the kernel (see ops.py).
@@ -37,6 +38,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import AltoEncoding
 
+# The recursive kernels' nonzero block, and the default (and smallest) block
+# of the oriented ones.
 DEFAULT_BLOCK_M = 1024
 # Elements per iteration of the scalar loops (manual unroll: Mosaic only
 # lowers fori_loop with unroll=1 or a full unroll).
@@ -139,8 +142,7 @@ def pad_partitions(L: int, block_m: int, cols, values, pi=None):
             None if pi is None else extend(pi, 0), cp)
 
 
-def _mttkrp_partial_kernel(enc: AltoEncoding, mode: int, block_m: int,
-                           start_ref, *refs):
+def _mttkrp_partial_kernel(enc: AltoEncoding, mode: int, start_ref, *refs):
     """Grid step (partition l, rank tile r, block b): Temp_l += block."""
     W, n_other = enc.n_words, enc.ndim - 1
     word_refs, vals_ref = refs[:W], refs[W]
@@ -161,16 +163,16 @@ def _mttkrp_partial_kernel(enc: AltoEncoding, mode: int, block_m: int,
         out_ref[0, pl.ds(local, 1), :] += contrib.astype(out_ref.dtype)
         return state
 
-    _for_each(block_m, body, 0)
+    _for_each(DEFAULT_BLOCK_M, body, 0)
 
 
 def mttkrp_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
                            words: jnp.ndarray, values: jnp.ndarray,
                            part_start: jnp.ndarray, factors,
                            r_block: int | None = None,
-                           block_m: int = DEFAULT_BLOCK_M,
                            interpret: bool = True) -> jnp.ndarray:
     """Per-partition Temp buffers: (L, temp_rows, R)."""
+    block_m = DEFAULT_BLOCK_M
     L = part_start.shape[0]
     R = factors[0].shape[1]
     rb = r_block or R
@@ -192,7 +194,7 @@ def mttkrp_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
         + [resident((f.shape[0], rb), lambda l, r, b: (0, r))
            for f in others])
     return pl.pallas_call(
-        functools.partial(_mttkrp_partial_kernel, enc, mode, block_m),
+        functools.partial(_mttkrp_partial_kernel, enc, mode),
         grid=(L, R // rb, nbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, temp_rows, rb),

@@ -102,9 +102,11 @@ def _segment_matmul(seg, contrib):
     onehot = (seg[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (block_m, block_m), 1)).astype(contrib.dtype)
     # Contracting the element axis of an element-major one-hot keeps the
-    # per-segment sums in element order, bit-for-bit the carry scan's.
+    # per-segment sums in element order, bit-for-bit the carry scan's;
+    # HIGHEST keeps the MXU from rounding f32 contributions to bf16.
     return jax.lax.dot_general(
         onehot, contrib, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(contrib.dtype)
 
 

@@ -270,7 +270,6 @@ def delinearize(enc: AltoEncoding, words: jnp.ndarray,
 
 def mttkrp(at: AltoTensor, factors, mode: int,
            r_block: int | None = None,
-           block_m: int = _mttkrp.DEFAULT_BLOCK_M,
            interpret: bool | None = None) -> jnp.ndarray:
     """Recursive-traversal MTTKRP: Pallas partials kernel + pull reduction."""
     meta = at.meta
@@ -283,14 +282,12 @@ def mttkrp(at: AltoTensor, factors, mode: int,
         def run(words, values, part_start, factors):
             partials = _mttkrp.mttkrp_partials_pallas(
                 meta.enc, mode, meta.temp_rows[mode], words, values,
-                part_start, factors, r_block=rb, block_m=block_m,
-                interpret=interp)
+                part_start, factors, r_block=rb, interpret=interp)
             return pull_reduction(partials, part_start[:, mode],
                                   meta.dims[mode])
         return jax.jit(run)
 
-    fn = _cached_executable(("mttkrp_rec", meta, mode, rb, block_m, interp),
-                            build)
+    fn = _cached_executable(("mttkrp_rec", meta, mode, rb, interp), build)
     return fn(at.words, at.values, at.part_start, list(factors))
 
 
@@ -357,7 +354,6 @@ def mttkrp_oriented_carry(view: OrientedView, factors,
 def cpapr_phi(at: AltoTensor, B: jnp.ndarray, mode: int,
               factors=None, pi: jnp.ndarray | None = None,
               eps: float = 1e-10,
-              block_m: int = _mttkrp.DEFAULT_BLOCK_M,
               interpret: bool | None = None) -> jnp.ndarray:
     """Recursive-traversal fused Φ: Pallas partials kernel + pull reduction."""
     meta = at.meta
@@ -370,14 +366,13 @@ def cpapr_phi(at: AltoTensor, B: jnp.ndarray, mode: int,
         def run(words, values, part_start, B, factors, pi):
             partials = _phi.phi_partials_pallas(
                 meta.enc, mode, meta.temp_rows[mode], eps, words, values,
-                part_start, B, factors=factors, pi=pi, block_m=block_m,
-                interpret=interp)
+                part_start, B, factors=factors, pi=pi, interpret=interp)
             return pull_reduction(partials, part_start[:, mode],
                                   meta.dims[mode])
         return jax.jit(run)
 
     fn = _cached_executable(
-        ("phi_rec", meta, mode, eps, pre_pi, block_m, interp), build)
+        ("phi_rec", meta, mode, eps, pre_pi, interp), build)
     return fn(at.words, at.values, at.part_start, B,
               list(factors) if factors is not None else None, pi)
 

@@ -97,7 +97,7 @@ def test_recursive_mttkrp_compiles(one_chip):
     s, factors, _, sds = _shapes(one_chip, meta)
     fn = functools.partial(mttkrp.mttkrp_partials_pallas, meta.enc, mode,
                            meta.temp_rows[mode], r_block=mp.r_block,
-                           block_m=mp.block_m, interpret=False)
+                           interpret=False)
     _compile_fits(fn, s["words"], s["values"],
                   sds((meta.n_partitions, 3), jnp.int32), factors)
 
@@ -147,10 +147,10 @@ def test_recursive_phi_compiles(one_chip):
     meta = _meta(reuse=100.0)
     mode = 0
     mp = _mode_plan(meta, mode, Traversal.RECURSIVE)
+    assert mp.block_m == plan_mod.MIN_BLOCK_M      # the kernel's fixed block
     s, factors, B, sds = _shapes(one_chip, meta, mode=mode, B=True)
     fn = functools.partial(cpapr_phi.phi_partials_pallas, meta.enc, mode,
-                           meta.temp_rows[mode], 1e-10,
-                           block_m=mp.block_m, interpret=False)
+                           meta.temp_rows[mode], 1e-10, interpret=False)
     _compile_fits(lambda w, v, ps, b, f: fn(w, v, ps, b, factors=f),
                   s["words"], s["values"],
                   sds((meta.n_partitions, 3), jnp.int32), B, factors)
