@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import encoding as enc_mod
+from repro.core import telemetry
 from repro.core.encoding import AltoEncoding, make_encoding
 from repro.sparse.tensor import SparseTensor
 
@@ -259,7 +260,6 @@ def oriented_view(at: AltoTensor, mode: int) -> OrientedView:
 _DEVICE_INGEST_FNS: "collections.OrderedDict[tuple, object]" = \
     collections.OrderedDict()
 _DEVICE_INGEST_FNS_MAX = 128
-_DEVICE_INGEST_TRACES = {"build": 0, "view": 0, "merge": 0}
 # Concurrent serving drivers ingest in parallel; the OrderedDict
 # move_to_end/popitem pair is not atomic, so guard all mutations.
 _DEVICE_INGEST_LOCK = threading.Lock()
@@ -278,11 +278,12 @@ def _cached_ingest_fn(key: tuple, build):
 
 
 def device_ingest_traces() -> dict[str, int]:
-    """Trace counts of the jitted build/view cores (tests pin the
+    """Trace counts of the jitted build/view/merge cores (tests pin the
     once-per-meta contract with this; the serving layer pins its
     one-trace-per-shape-class contract with before/after deltas)."""
-    with _DEVICE_INGEST_LOCK:
-        return dict(_DEVICE_INGEST_TRACES)
+    c = telemetry.counts()
+    return {k: c.get(f"ingest.{k}.trace", 0)
+            for k in ("build", "view", "merge")}
 
 
 def _build_device_fn(enc: AltoEncoding, L: int, M: int, val_dtype):
@@ -292,8 +293,8 @@ def _build_device_fn(enc: AltoEncoding, L: int, M: int, val_dtype):
     chunk = -(-max(M, L) // L)
     Mp = chunk * L
 
+    @telemetry.traced("ingest.build.trace")
     def core(coords, values):
-        _DEVICE_INGEST_TRACES["build"] += 1              # trace-time only
         words, values = enc_mod.sort_by_key(linearize(enc, coords), values)
         if Mp > M:
             # Same padding rule as build(): value-0 copies of the last
@@ -367,20 +368,21 @@ def build_device(x: SparseTensor, n_partitions: int = 8,
     duplicate linearized keys keep COO input order), same padding, same
     static meta.
     """
-    enc = make_encoding(x.dims)
-    L = max(1, int(n_partitions))
-    M = x.nnz
-    coords = jnp.asarray(x.coords)
-    values = jnp.asarray(x.values)
-    fn = _build_device_fn(enc, L, M, values.dtype)
-    return finalize_device(enc, M, L, *fn(coords, values),
-                           compute_reuse=compute_reuse)
+    with telemetry.span("ingest.build_device"):
+        enc = make_encoding(x.dims)
+        L = max(1, int(n_partitions))
+        M = x.nnz
+        coords = jnp.asarray(x.coords)
+        values = jnp.asarray(x.values)
+        fn = _build_device_fn(enc, L, M, values.dtype)
+        return finalize_device(enc, M, L, *fn(coords, values),
+                               compute_reuse=compute_reuse)
 
 
 def _view_rows_fn(enc: AltoEncoding, mode: int, Mp: int):
     """The cached jitted target-row extraction for one static meta/mode."""
+    @telemetry.traced("ingest.view.trace")
     def core(words):
-        _DEVICE_INGEST_TRACES["view"] += 1               # trace-time only
         return enc_mod.extract_mode(enc, words, mode)    # (Mp,) int32
 
     return _cached_ingest_fn(("view", enc, mode, Mp), lambda: jax.jit(core))

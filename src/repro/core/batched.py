@@ -50,6 +50,7 @@ import numpy as np
 from repro.core import cpals, cpapr, faults
 from repro.core import health as health_mod
 from repro.core import plan as plan_mod
+from repro.core import telemetry
 from repro.core.alto import AltoTensor, OrientedView
 
 
@@ -58,7 +59,6 @@ from repro.core.alto import AltoTensor, OrientedView
 # capacity, so one entry per key is one XLA executable. Guarded like the
 # ingest cache — serving drivers hit this from worker threads.
 _SWEEP_FNS: dict[tuple, object] = {}
-_SWEEP_TRACES = {"als": 0, "apr": 0}
 _SWEEP_LOCK = threading.Lock()
 
 
@@ -66,15 +66,13 @@ def sweep_traces() -> dict[str, int]:
     """Trace counts of the batched cores (per algorithm). The serving
     acceptance test asserts the delta is bounded by the number of shape
     classes, never the number of tenants."""
-    with _SWEEP_LOCK:
-        return dict(_SWEEP_TRACES)
+    c = telemetry.counts()
+    return {k: c.get(f"batched.{k}.trace", 0) for k in ("als", "apr")}
 
 
 def sweep_cache_clear() -> None:
     with _SWEEP_LOCK:
         _SWEEP_FNS.clear()
-        _SWEEP_TRACES["als"] = 0
-        _SWEEP_TRACES["apr"] = 0
 
 
 def _cached_sweep_fn(key: tuple, build):
@@ -123,9 +121,8 @@ def _slice_factors(factors, dims):
 
 def _als_sweep_fn(plan: plan_mod.ExecutionPlan):
     """One jitted batched ALS sweep: vmap of `cpals._sweep` + freeze mask."""
+    @telemetry.traced("batched.als.trace")
     def core(at, views, factors, lam, active):
-        with _SWEEP_LOCK:
-            _SWEEP_TRACES["als"] += 1                    # trace-time only
         new_factors, new_lam, M_last = jax.vmap(
             functools.partial(cpals._sweep, plan))(at, views, factors, lam)
         a3 = active[:, None, None]
@@ -280,9 +277,8 @@ def _apr_update_fn(plan: plan_mod.ExecutionPlan, mode: int,
                    first_outer: bool, pre_pi: bool, p: cpapr.CpaprParams):
     """One jitted batched CP-APR mode update: vmap of `cpapr._mode_update`
     + per-tenant freeze of factors[mode], λ, and the Φ memory."""
+    @telemetry.traced("batched.apr.trace")
     def core(at, view, lam, factors, phi_prev, active):
-        with _SWEEP_LOCK:
-            _SWEEP_TRACES["apr"] += 1                    # trace-time only
         def upd(t, v, l, f, ph):
             return cpapr._mode_update(t, v, mode, l, f, ph,
                                       first_outer=first_outer,

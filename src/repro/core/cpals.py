@@ -37,6 +37,7 @@ from repro.core import faults
 from repro.core import health as health_mod
 from repro.core import ingest as ingest_mod
 from repro.core import plan as plan_mod
+from repro.core import telemetry
 from repro.core.alto import AltoTensor, OrientedView
 from repro.core.mttkrp import mttkrp_adaptive
 
@@ -154,87 +155,104 @@ def cp_als(at: AltoTensor, rank: int, n_iters: int = 50, tol: float = 1e-5,
     inputs the guard changes nothing — the returned trajectory stays
     bitwise identical to an unguarded run.
     """
-    if factors is not None and warm_start is not None:
-        raise ValueError("pass factors= or warm_start=, not both")
-    if warm_start is not None:
-        lam_w, factors = ingest_mod.grow_factors(
-            warm_start, at.dims, rank, seed=seed, dtype=at.values.dtype)
-        if lam_w is not None:
-            # Fold the previous weights in so the first sweep starts at
-            # the previous MODEL, not its column-normalized shadow.
-            factors = list(factors)
-            factors[0] = factors[0] * lam_w[None, :]
-    if plan is None:
-        plan = plan_mod.make_plan(at.meta, rank, tune=tune, at=at)
-    elif plan.rank != rank:
-        raise ValueError(f"plan was built for rank {plan.rank}, "
-                         f"cp_als called with rank {rank}")
-    if at.meta.nnz == 0:
-        # Degenerate tenant input (a public serving endpoint WILL see
-        # these): the zero model is the exact decomposition. Well-defined
-        # result — zero factors, zero weights, fit 1.0 — not an exception
-        # or a NaN fit trajectory.
-        dtype = at.values.dtype
-        return CpalsResult(lam=jnp.zeros((rank,), dtype),
-                           factors=[jnp.zeros((I, rank), dtype)
-                                    for I in at.dims],
-                           fits=[1.0], n_iters=0, plan=plan)
-    if factors is None:
-        factors = init_factors(at.dims, rank, seed=seed,
-                               dtype=at.values.dtype)
-    if views is None:
-        views = plan_mod.build_views(at, plan)
-    lam = jnp.ones((rank,), dtype=at.values.dtype)
-    normX2 = float((np.asarray(at.values, np.float64) ** 2).sum())
+    with telemetry.span("cpals.call"):
+        with telemetry.span("cpals.prepare"):
+            if factors is not None and warm_start is not None:
+                raise ValueError("pass factors= or warm_start=, not both")
+            if warm_start is not None:
+                lam_w, factors = ingest_mod.grow_factors(
+                    warm_start, at.dims, rank, seed=seed,
+                    dtype=at.values.dtype)
+                if lam_w is not None:
+                    # Fold the previous weights in so the first sweep
+                    # starts at the previous MODEL, not its
+                    # column-normalized shadow.
+                    factors = list(factors)
+                    factors[0] = factors[0] * lam_w[None, :]
+            if plan is None:
+                plan = plan_mod.make_plan(at.meta, rank, tune=tune, at=at)
+            elif plan.rank != rank:
+                raise ValueError(f"plan was built for rank {plan.rank}, "
+                                 f"cp_als called with rank {rank}")
+            if at.meta.nnz == 0:
+                # Degenerate tenant input (a public serving endpoint WILL
+                # see these): the zero model is the exact decomposition.
+                # Well-defined result — zero factors, zero weights, fit
+                # 1.0 — not an exception or a NaN fit trajectory.
+                dtype = at.values.dtype
+                return CpalsResult(lam=jnp.zeros((rank,), dtype),
+                                   factors=[jnp.zeros((I, rank), dtype)
+                                            for I in at.dims],
+                                   fits=[1.0], n_iters=0, plan=plan)
+            if factors is None:
+                factors = init_factors(at.dims, rank, seed=seed,
+                                       dtype=at.values.dtype)
+            if views is None:
+                views = plan_mod.build_views(at, plan)
+            lam = jnp.ones((rank,), dtype=at.values.dtype)
+            normX2 = float((np.asarray(at.values, np.float64) ** 2).sum())
 
-    sweep_fn = functools.partial(_sweep, plan, gram_fn=gram_fn)
-    # Streaming (out-of-core) plans keep the sweep a host loop: the
-    # chunked executors are themselves host loops over per-chunk jitted
-    # calls, and a host-resident stream is not a jit operand. The dense
-    # algebra still runs the same XLA kernels per op.
-    sweep = sweep_fn if plan.streaming is not None else jax.jit(sweep_fn)
-    report = health_mod.HealthReport() if guard else None
-    fits: list[float] = []
-    prev_fit = -np.inf
-    it = 0
-    for it in range(1, n_iters + 1):
-        good = (factors, lam)
-        factors, lam, M_last = sweep(at, views, factors, lam)
-        pd = faults.fire("cpals.nan")
-        if pd is not None:
-            # Poison the LAST factor: the next sweep's first mode update
-            # consumes it through the Gram products, so an unguarded run
-            # propagates the poison everywhere (the realistic hazard).
-            poison = pd.get("value", float("nan"))
-            factors = list(factors)
-            factors[-1] = factors[-1].at[0, 0].set(poison)
-        fit = _fit_host(M_last, factors, lam, normX2)
-        if guard:
-            report.checks += 1
-            reason = None
-            if not np.isfinite(fit) or not health_mod.all_finite(
-                    [*factors, lam, M_last]):
-                reason = f"non-finite sweep output at iteration {it}"
-            elif fit < health_mod.FIT_FLOOR:
-                # Huge-but-finite iterate: must be stopped HERE — its
-                # Gram products overflow the next sweep (health.FIT_FLOOR)
-                reason = f"fit diverged to {fit:.3e} at iteration {it}"
-            elif fits and fit < fits[-1] - guard_slack:
-                reason = (f"fit regressed {fits[-1]:.6f} -> {fit:.6f} "
-                          f"at iteration {it}")
-            if reason is not None:
-                report.violations += 1
-                report.rolled_back = True
-                report.reason = reason
-                factors, lam = good
-                it -= 1
+            sweep_fn = functools.partial(_sweep, plan, gram_fn=gram_fn)
+            # Streaming (out-of-core) plans keep the sweep a host loop:
+            # the chunked executors are themselves host loops over
+            # per-chunk jitted calls, and a host-resident stream is not a
+            # jit operand. The dense algebra still runs the same XLA
+            # kernels per op.
+            if plan.streaming is not None:
+                sweep = sweep_fn
+            else:
+                def sweep(*args):
+                    with telemetry.traced("cpals.trace"):
+                        return sweep_fn(*args)
+                sweep = jax.jit(sweep)
+        report = health_mod.HealthReport() if guard else None
+        fits: list[float] = []
+        prev_fit = -np.inf
+        it = 0
+        for it in range(1, n_iters + 1):
+            good = (factors, lam)
+            # Holds any trace, lowering or compile the call needs.
+            with telemetry.span("cpals.dispatch", it=it):
+                factors, lam, M_last = sweep(at, views, factors, lam)
+            pd = faults.fire("cpals.nan")
+            if pd is not None:
+                # Poison the LAST factor: the next sweep's first mode
+                # update consumes it through the Gram products, so an
+                # unguarded run propagates the poison everywhere (the
+                # realistic hazard).
+                poison = pd.get("value", float("nan"))
+                factors = list(factors)
+                factors[-1] = factors[-1].at[0, 0].set(poison)
+            # The host round trip: waits for the sweep, copies to float64.
+            with telemetry.span("cpals.fit", it=it):
+                fit = _fit_host(M_last, factors, lam, normX2)
+            if guard:
+                report.checks += 1
+                reason = None
+                if not np.isfinite(fit) or not health_mod.all_finite(
+                        [*factors, lam, M_last]):
+                    reason = f"non-finite sweep output at iteration {it}"
+                elif fit < health_mod.FIT_FLOOR:
+                    # Huge-but-finite iterate: must be stopped HERE — its
+                    # Gram products overflow the next sweep
+                    # (health.FIT_FLOOR)
+                    reason = f"fit diverged to {fit:.3e} at iteration {it}"
+                elif fits and fit < fits[-1] - guard_slack:
+                    reason = (f"fit regressed {fits[-1]:.6f} -> {fit:.6f} "
+                              f"at iteration {it}")
+                if reason is not None:
+                    report.violations += 1
+                    report.rolled_back = True
+                    report.reason = reason
+                    factors, lam = good
+                    it -= 1
+                    break
+            fits.append(fit)
+            if abs(fit - prev_fit) < tol:
                 break
-        fits.append(fit)
-        if abs(fit - prev_fit) < tol:
-            break
-        prev_fit = fit
-    return CpalsResult(lam=lam, factors=list(factors), fits=fits,
-                       n_iters=it, plan=plan, health=report)
+            prev_fit = fit
+        return CpalsResult(lam=lam, factors=list(factors), fits=fits,
+                           n_iters=it, plan=plan, health=report)
 
 
 def reconstruct_values(coords: jnp.ndarray, lam: jnp.ndarray,

@@ -32,6 +32,7 @@ from repro.core import health as health_mod
 from repro.core import heuristics
 from repro.core import ingest as ingest_mod
 from repro.core import plan as plan_mod
+from repro.core import telemetry
 from repro.core.alto import AltoTensor, OrientedView, delinearize
 from repro.core.mttkrp import krp_rows
 
@@ -86,51 +87,55 @@ def _mode_update(at: AltoTensor, view: OrientedView | None, mode: int,
                  pre_pi: bool, p: CpaprParams,
                  plan: plan_mod.ExecutionPlan):
     """One full Alg. 2 mode update (lines 4-15), jit-able."""
-    A = factors[mode]
-    # Line 4: inadmissible-zero adjustment (skipped on the first outer iter).
-    if first_outer:
-        S = jnp.zeros_like(A)
-    else:
-        S = jnp.where((A < p.kappa_tol) & (phi_prev > 1.0), p.kappa, 0.0)
-    B0 = (A + S) * lam[None, :]                       # line 5: B = (A+S)Λ
+    with telemetry.traced("cpapr.trace", mode=mode,
+                          first_outer=first_outer):
+        A = factors[mode]
+        # Line 4: inadmissible-zero adjustment (skipped on the first
+        # outer iteration).
+        if first_outer:
+            S = jnp.zeros_like(A)
+        else:
+            S = jnp.where((A < p.kappa_tol) & (phi_prev > 1.0), p.kappa, 0.0)
+        B0 = (A + S) * lam[None, :]                       # line 5: B = (A+S)Λ
 
-    if pre_pi:
-        # Line 6 (Π, M×R rows) in the element order the plan's traversal
-        # will consume (oriented modes read the view-permuted stream).
-        oriented = (view is not None
-                    and heuristics.is_oriented(
-                        plan.modes[mode].traversal))
-        words = view.words if oriented else at.words
-        coords = delinearize(at.meta.enc, words)
-        pi = krp_rows(coords, factors, mode)
+        if pre_pi:
+            # Line 6 (Π, M×R rows) in the element order the plan's traversal
+            # will consume (oriented modes read the view-permuted stream).
+            oriented = (view is not None
+                        and heuristics.is_oriented(
+                            plan.modes[mode].traversal))
+            words = view.words if oriented else at.words
+            coords = delinearize(at.meta.enc, words)
+            pi = krp_rows(coords, factors, mode)
 
-    def phi_of(B):                                    # lines 8-9
-        return plan_mod.execute_phi(
-            plan, at, view, B, mode,
-            factors=None if pre_pi else factors,
-            pi=pi if pre_pi else None, eps=p.eps_div)
+        def phi_of(B):                                    # lines 8-9
+            return plan_mod.execute_phi(
+                plan, at, view, B, mode,
+                factors=None if pre_pi else factors,
+                pi=pi if pre_pi else None, eps=p.eps_div)
 
-    def inner(carry, _):
-        B, done, n_inner = carry
-        Phi = phi_of(B)                               # line 8
-        kkt = jnp.max(jnp.abs(jnp.minimum(B, 1.0 - Phi)))  # line 9
-        now_done = done | (kkt < p.tau)
-        B_new = jnp.where(now_done, B, B * Phi)       # line 13 (frozen after
-        n_inner = n_inner + jnp.where(now_done, 0, 1)  # convergence)
-        return (B_new, now_done, n_inner), (Phi, kkt)
+        def inner(carry, _):
+            B, done, n_inner = carry
+            Phi = phi_of(B)                               # line 8
+            kkt = jnp.max(jnp.abs(jnp.minimum(B, 1.0 - Phi)))  # line 9
+            now_done = done | (kkt < p.tau)
+            # Line 13, frozen after convergence.
+            B_new = jnp.where(now_done, B, B * Phi)
+            n_inner = n_inner + jnp.where(now_done, 0, 1)
+            return (B_new, now_done, n_inner), (Phi, kkt)
 
-    (B, done, n_inner), (phis, kkts) = jax.lax.scan(
-        inner, (B0, jnp.asarray(False), jnp.asarray(0, jnp.int32)),
-        None, length=p.l_max)
-    Phi_last = phis[-1]
+        (B, done, n_inner), (phis, kkts) = jax.lax.scan(
+            inner, (B0, jnp.asarray(False), jnp.asarray(0, jnp.int32)),
+            None, length=p.l_max)
+        Phi_last = phis[-1]
 
-    lam_new = jnp.sum(B, axis=0)                      # line 15: λ = eᵀB
-    lam_new = jnp.where(lam_new > 0, lam_new, 1.0)
-    A_new = B / lam_new[None, :]
-    # Mode converged iff no inner update was applied.
-    mode_converged = n_inner == 0
-    kkt_first = kkts[0]
-    return A_new, lam_new, Phi_last, mode_converged, n_inner, kkt_first
+        lam_new = jnp.sum(B, axis=0)                      # line 15: λ = eᵀB
+        lam_new = jnp.where(lam_new > 0, lam_new, 1.0)
+        A_new = B / lam_new[None, :]
+        # Mode converged iff no inner update was applied.
+        mode_converged = n_inner == 0
+        kkt_first = kkts[0]
+        return A_new, lam_new, Phi_last, mode_converged, n_inner, kkt_first
 
 
 def _mode_update_streaming(at: AltoTensor, view, mode: int,
@@ -220,97 +225,111 @@ def cp_apr(at: AltoTensor, rank: int, params: CpaprParams | None = None,
     """
     p = params or CpaprParams()
     N = len(at.dims)
-    if at.meta.nnz == 0:
-        # Degenerate tenant input: the zero model maximizes the Poisson
-        # likelihood of an all-zero tensor (λ → 0). Return a well-defined
-        # converged result instead of iterating on NaNs.
-        dtype = at.values.dtype
-        return CpaprResult(
-            lam=jnp.zeros((rank,), dtype),
-            factors=[jnp.zeros((I, rank), dtype) for I in at.dims],
-            kkt_violations=[0.0], log_likelihoods=[], n_outer=0,
-            n_inner_total=0, pi_policy=pi_policy or "otf",
-            traversals=["oriented"] * N,
-            plan=plan)
-    total = float(jnp.sum(at.values))
-    if warm_start is not None:
-        lam, factors = ingest_mod.grow_factors(
-            warm_start, at.dims, rank, seed=seed, dtype=at.values.dtype,
-            positive=True)
-        if lam is None:
-            lam = jnp.full((rank,), total / rank, dtype=at.values.dtype)
-    else:
-        lam, factors = init_factors(at.dims, rank, seed=seed, total=total,
-                                    dtype=at.values.dtype)
+    with telemetry.span("cpapr.call"):
+        if at.meta.nnz == 0:
+            # Degenerate tenant input: the zero model maximizes the
+            # Poisson likelihood of an all-zero tensor (λ → 0). Return a
+            # well-defined converged result instead of iterating on NaNs.
+            dtype = at.values.dtype
+            return CpaprResult(
+                lam=jnp.zeros((rank,), dtype),
+                factors=[jnp.zeros((I, rank), dtype) for I in at.dims],
+                kkt_violations=[0.0], log_likelihoods=[], n_outer=0,
+                n_inner_total=0, pi_policy=pi_policy or "otf",
+                traversals=["oriented"] * N,
+                plan=plan)
+        with telemetry.span("cpapr.prepare"):
+            total = float(jnp.sum(at.values))
+            if warm_start is not None:
+                lam, factors = ingest_mod.grow_factors(
+                    warm_start, at.dims, rank, seed=seed,
+                    dtype=at.values.dtype, positive=True)
+                if lam is None:
+                    lam = jnp.full((rank,), total / rank,
+                                   dtype=at.values.dtype)
+            else:
+                lam, factors = init_factors(at.dims, rank, seed=seed,
+                                            total=total,
+                                            dtype=at.values.dtype)
 
-    if plan is None:
-        plan = plan_mod.make_plan(at.meta, rank, tune=tune,
-                                  tune_objective="phi", at=at)
-    elif plan.rank != rank:
-        raise ValueError(f"plan was built for rank {plan.rank}, "
-                         f"cp_apr called with rank {rank}")
-    if pi_policy is None:
-        pi_policy = plan.pi_policy.value
-    pre_pi = pi_policy == "pre"
+            if plan is None:
+                plan = plan_mod.make_plan(at.meta, rank, tune=tune,
+                                          tune_objective="phi", at=at)
+            elif plan.rank != rank:
+                raise ValueError(f"plan was built for rank {plan.rank}, "
+                                 f"cp_apr called with rank {rank}")
+            if pi_policy is None:
+                pi_policy = plan.pi_policy.value
+            pre_pi = pi_policy == "pre"
 
-    if views is None:
-        views = plan_mod.build_views(at, plan)
-    traversals = [plan.modes[n].traversal.value
-                  if (n in views
-                      and heuristics.is_oriented(plan.modes[n].traversal))
-                  else "recursive" for n in range(N)]
+            if views is None:
+                views = plan_mod.build_views(at, plan)
+            traversals = [plan.modes[n].traversal.value
+                          if (n in views and heuristics.is_oriented(
+                              plan.modes[n].traversal))
+                          else "recursive" for n in range(N)]
 
-    if plan.streaming is not None:
-        # Out-of-core: the chunked Φ executor is a host loop over
-        # per-chunk jitted calls, and a HostStream is not a jit operand.
-        update = _mode_update_streaming
-    else:
-        update = jax.jit(_mode_update,
-                         static_argnames=("mode", "first_outer", "pre_pi",
-                                          "p", "plan"))
+            if plan.streaming is not None:
+                # Out-of-core: the chunked Φ executor is a host loop over
+                # per-chunk jitted calls, and a HostStream is not a jit
+                # operand.
+                update = _mode_update_streaming
+            else:
+                update = jax.jit(_mode_update,
+                                 static_argnames=("mode", "first_outer",
+                                                  "pre_pi", "p", "plan"))
 
-    phi_prev = [jnp.zeros_like(A) for A in factors]
-    report = health_mod.HealthReport() if guard else None
-    kkt_hist: list[float] = []
-    ll_hist: list[float] = []
-    n_inner_total = 0
-    outer = 0
-    for outer in range(1, p.k_max + 1):
-        # Last good state for the guard's rollback (references only —
-        # the arrays are immutable, nothing is copied).
-        good = (lam, list(factors), list(phi_prev))
-        all_converged = True
-        kkt_max = 0.0
-        for n in range(N):
-            A, lam, phi_n, conv, n_inner, kkt = update(
-                at, views.get(n), n, lam, factors, phi_prev[n],
-                first_outer=(outer == 1), pre_pi=pre_pi, p=p, plan=plan)
-            pd = faults.fire("cpapr.nan")
-            if pd is not None:
-                A = A.at[0, 0].set(pd.get("value", float("nan")))
-            factors = list(factors)
-            factors[n] = A
-            phi_prev[n] = phi_n
-            n_inner_total += int(n_inner)
-            all_converged &= bool(conv)
-            kkt_max = max(kkt_max, float(kkt))
-        if guard:
-            report.checks += 1
-            if not np.isfinite(kkt_max) or not health_mod.all_finite(
-                    [lam, *factors]):
-                report.violations += 1
-                report.rolled_back = True
-                report.reason = (f"non-finite mode update at outer "
-                                 f"iteration {outer}")
-                lam, factors, phi_prev = good
-                outer -= 1
-                break
-        kkt_hist.append(kkt_max)
-        if track_ll:
-            ll_hist.append(float(log_likelihood(at, lam, factors)))
-        if all_converged:                              # lines 17-19
-            break
-    return CpaprResult(lam=lam, factors=factors, kkt_violations=kkt_hist,
-                       log_likelihoods=ll_hist, n_outer=outer,
-                       n_inner_total=n_inner_total, pi_policy=pi_policy,
-                       traversals=traversals, plan=plan, health=report)
+        phi_prev = [jnp.zeros_like(A) for A in factors]
+        report = health_mod.HealthReport() if guard else None
+        kkt_hist: list[float] = []
+        ll_hist: list[float] = []
+        n_inner_total = 0
+        outer = 0
+        for outer in range(1, p.k_max + 1):
+            with telemetry.span("cpapr.outer", outer=outer):
+                # Last good state for the guard's rollback (references
+                # only — the arrays are immutable, nothing is copied).
+                good = (lam, list(factors), list(phi_prev))
+                all_converged = True
+                kkt_max = 0.0
+                for n in range(N):
+                    # Holds any trace, lowering or compile the call needs.
+                    with telemetry.span("cpapr.dispatch", mode=n,
+                                        first_outer=outer == 1):
+                        A, lam, phi_n, conv, n_inner, kkt = update(
+                            at, views.get(n), n, lam, factors, phi_prev[n],
+                            first_outer=(outer == 1), pre_pi=pre_pi, p=p,
+                            plan=plan)
+                    pd = faults.fire("cpapr.nan")
+                    if pd is not None:
+                        A = A.at[0, 0].set(pd.get("value", float("nan")))
+                    factors = list(factors)
+                    factors[n] = A
+                    phi_prev[n] = phi_n
+                    # The host reads that wait for the mode's update.
+                    with telemetry.span("cpapr.sync", mode=n):
+                        n_inner_total += int(n_inner)
+                        all_converged &= bool(conv)
+                        kkt_max = max(kkt_max, float(kkt))
+                if guard:
+                    report.checks += 1
+                    if not np.isfinite(kkt_max) or not health_mod.all_finite(
+                            [lam, *factors]):
+                        report.violations += 1
+                        report.rolled_back = True
+                        report.reason = (f"non-finite mode update at outer "
+                                         f"iteration {outer}")
+                        lam, factors, phi_prev = good
+                        outer -= 1
+                        break
+                kkt_hist.append(kkt_max)
+                if track_ll:
+                    ll_hist.append(float(log_likelihood(at, lam, factors)))
+                if all_converged:                          # lines 17-19
+                    break
+        return CpaprResult(lam=lam, factors=factors,
+                           kkt_violations=kkt_hist,
+                           log_likelihoods=ll_hist, n_outer=outer,
+                           n_inner_total=n_inner_total,
+                           pi_policy=pi_policy, traversals=traversals,
+                           plan=plan, health=report)

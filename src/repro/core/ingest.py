@@ -53,6 +53,7 @@ import numpy as np
 from repro.core import alto
 from repro.core import encoding as enc_mod
 from repro.core import faults
+from repro.core import telemetry
 from repro.core import views as views_mod
 from repro.core.alto import AltoTensor
 from repro.core.encoding import AltoEncoding, make_encoding
@@ -82,8 +83,8 @@ def _merge_device_fn(old_enc: AltoEncoding, new_enc: AltoEncoding, L: int,
     chunk = -(-max(MD, L) // L)
     Mp = chunk * L
 
+    @telemetry.traced("ingest.merge.trace")
     def core(res_words, res_values, delta, delta_values):
-        alto._DEVICE_INGEST_TRACES["merge"] += 1         # trace-time only
         rw = res_words[:M]
         if new_enc != old_enc:
             # Extent growth re-assigned index bits: exact integer

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from repro.core import faults
 from repro.core import heuristics
 from repro.core import mttkrp as core_mttkrp
+from repro.core import telemetry
 from repro.core.alto import AltoMeta, AltoTensor, OrientedView, delinearize
 from repro.kernels.mttkrp import DEFAULT_BLOCK_M
 
@@ -777,57 +778,61 @@ def make_plan(meta: AltoMeta, rank: int, *, backend: str | None = None,
     round-trips across processes through the store file
     (``$REPRO_PLAN_CACHE`` or the checkout's ``.cache/plans.json``).
     """
-    backend = backend or default_backend()
-    if backend not in ("pallas", "reference"):
-        raise ValueError(f"unknown backend {backend!r}")
-    if tune not in ("off", "auto", "force", "search"):
-        raise ValueError(f"unknown tune mode {tune!r}")
-    if device_bytes is None:
-        device_bytes = default_device_bytes()
-    streaming_needed = (device_bytes is not None
-                        and needs_streaming(meta, rank, device_bytes,
-                                            dtype_bytes))
-    if streaming_needed and mesh is not None:
-        raise ValueError("out-of-core streaming does not compose with "
-                         "mesh-sharded plans yet (shard first, then size "
-                         "device_bytes per shard)")
-    if mesh is not None:
-        from repro.dist.meshes import auto_axes
-        mesh = auto_axes(mesh)
-    if tune != "off":
-        from repro.core import autotune
-        tuned = autotune.tuned_plan(
-            meta, rank, backend=backend, interpret=interpret,
-            dtype_bytes=dtype_bytes, vmem_limit=vmem_limit,
-            fast_mem_bytes=fast_mem_bytes, mesh=mesh, at=at,
-            require=(tune == "force"), objective=tune_objective,
-            search=(tune == "search"),
-            device_bytes=device_bytes if streaming_needed else None,
-            search_budget_runs=search_budget,
-            search_budget_s=search_seconds, search_seed=search_seed,
-            store_path=store_path)
-        if tuned is not None:
-            return tuned
-    pi_policy = heuristics.choose_pi_policy(
-        meta, rank, value_bytes=dtype_bytes, fast_mem_bytes=fast_mem_bytes)
-    modes = tuple(
-        static_mode_plan(meta, n, rank, dtype_bytes=dtype_bytes,
-                         vmem_limit=vmem_limit,
-                         force_oriented=mesh is not None,
-                         force_carry=streaming_needed,
-                         pre_pi=pi_policy is heuristics.PiPolicy.PRE)
-        for n in range(meta.enc.ndim))
-    streaming = None
-    if streaming_needed:
-        align = max(m.block_m for m in modes)
-        cm = choose_chunk_m(meta, rank, device_bytes, align, dtype_bytes)
-        streaming = StreamPlan(
-            chunk_m=cm, n_chunks=chunk_count(meta, cm),
-            device_bytes=device_bytes,
-            stream_bytes=incore_working_set_bytes(meta, rank, dtype_bytes))
-    return ExecutionPlan(meta=meta, rank=rank, backend=backend,
-                         interpret=interpret, pi_policy=pi_policy,
-                         modes=modes, mesh=mesh, streaming=streaming)
+    with telemetry.span("ingest.make_plan"):
+        backend = backend or default_backend()
+        if backend not in ("pallas", "reference"):
+            raise ValueError(f"unknown backend {backend!r}")
+        if tune not in ("off", "auto", "force", "search"):
+            raise ValueError(f"unknown tune mode {tune!r}")
+        if device_bytes is None:
+            device_bytes = default_device_bytes()
+        streaming_needed = (device_bytes is not None
+                            and needs_streaming(meta, rank, device_bytes,
+                                                dtype_bytes))
+        if streaming_needed and mesh is not None:
+            raise ValueError("out-of-core streaming does not compose "
+                             "with mesh-sharded plans yet (shard first, "
+                             "then size device_bytes per shard)")
+        if mesh is not None:
+            from repro.dist.meshes import auto_axes
+            mesh = auto_axes(mesh)
+        if tune != "off":
+            from repro.core import autotune
+            tuned = autotune.tuned_plan(
+                meta, rank, backend=backend, interpret=interpret,
+                dtype_bytes=dtype_bytes, vmem_limit=vmem_limit,
+                fast_mem_bytes=fast_mem_bytes, mesh=mesh, at=at,
+                require=(tune == "force"), objective=tune_objective,
+                search=(tune == "search"),
+                device_bytes=device_bytes if streaming_needed else None,
+                search_budget_runs=search_budget,
+                search_budget_s=search_seconds, search_seed=search_seed,
+                store_path=store_path)
+            if tuned is not None:
+                return tuned
+        pi_policy = heuristics.choose_pi_policy(
+            meta, rank, value_bytes=dtype_bytes,
+            fast_mem_bytes=fast_mem_bytes)
+        modes = tuple(
+            static_mode_plan(meta, n, rank, dtype_bytes=dtype_bytes,
+                             vmem_limit=vmem_limit,
+                             force_oriented=mesh is not None,
+                             force_carry=streaming_needed,
+                             pre_pi=pi_policy is heuristics.PiPolicy.PRE)
+            for n in range(meta.enc.ndim))
+        streaming = None
+        if streaming_needed:
+            align = max(m.block_m for m in modes)
+            cm = choose_chunk_m(meta, rank, device_bytes, align,
+                                dtype_bytes)
+            streaming = StreamPlan(
+                chunk_m=cm, n_chunks=chunk_count(meta, cm),
+                device_bytes=device_bytes,
+                stream_bytes=incore_working_set_bytes(meta, rank,
+                                                      dtype_bytes))
+        return ExecutionPlan(meta=meta, rank=rank, backend=backend,
+                             interpret=interpret, pi_policy=pi_policy,
+                             modes=modes, mesh=mesh, streaming=streaming)
 
 
 def plan_for(at: AltoTensor, rank: int, **kwargs) -> ExecutionPlan:
@@ -865,8 +870,9 @@ def build_views(at: AltoTensor, plan: ExecutionPlan,
     ``route`` picks the device (`alto.oriented_view_device`, default) or
     host builder — bit-identical, so the cache ignores the route.
     """
-    from repro.core import views as views_mod
-    return views_mod.build_views(at, plan, route=route)
+    with telemetry.span("ingest.build_views"):
+        from repro.core import views as views_mod
+        return views_mod.build_views(at, plan, route=route)
 
 
 def resident_bytes(at: AltoTensor,

@@ -102,6 +102,7 @@ def phi_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
 
     return pl.pallas_call(
         functools.partial(_phi_partial_kernel, enc, mode, eps, pre_pi),
+        name="alto_phi_recursive",
         grid=(L, nbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, temp_rows, R), lambda l, b: (l, 0, 0)),

@@ -42,6 +42,7 @@ def delinearize_pallas(enc: AltoEncoding, words: jnp.ndarray,
         raise ValueError(f"M={M} not a multiple of block_m={block_m}")
     return pl.pallas_call(
         functools.partial(_delinearize_kernel, enc),
+        name="alto_delinearize",
         grid=(M // block_m,),
         in_specs=[pl.BlockSpec((block_m,), lambda i: (i,))] * W,
         out_specs=pl.BlockSpec((enc.ndim, block_m), lambda i: (0, i)),

@@ -195,6 +195,7 @@ def mttkrp_partials_pallas(enc: AltoEncoding, mode: int, temp_rows: int,
            for f in others])
     return pl.pallas_call(
         functools.partial(_mttkrp_partial_kernel, enc, mode),
+        name="alto_mttkrp_recursive",
         grid=(L, R // rb, nbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, temp_rows, rb),

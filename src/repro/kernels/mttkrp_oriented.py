@@ -168,6 +168,7 @@ def mttkrp_oriented_partials_pallas(enc: AltoEncoding, mode: int,
                    for f in others])
     return pl.pallas_call(
         functools.partial(_mttkrp_oriented_kernel, enc, mode, block_m),
+        name="alto_mttkrp_oriented",
         grid=(n_blocks, R // rb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_m, rb), lambda b, r: (b, 0, r)),
@@ -243,6 +244,7 @@ def phi_oriented_partials_pallas(enc: AltoEncoding, mode: int, eps: float,
     return pl.pallas_call(
         functools.partial(_phi_oriented_kernel, enc, mode, eps, pre_pi,
                           block_m),
+        name="alto_phi_oriented",
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((block_m,), lambda b: (b,)), smem, smem,
                   b_spec] + word_specs + op_specs,
@@ -375,6 +377,7 @@ def mttkrp_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
     acc_idx = len(in_specs) - 1
     return pl.pallas_call(
         functools.partial(_mttkrp_carry_kernel, enc, mode, block_m),
+        name="alto_mttkrp_carry",
         grid=(R // rb, M // block_m),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -485,6 +488,7 @@ def phi_oriented_carry_chunk_pallas(enc: AltoEncoding, mode: int,
     return pl.pallas_call(
         functools.partial(_phi_carry_kernel, enc, mode, eps, pre_pi,
                           block_m),
+        name="alto_phi_carry",
         grid=(M // block_m,),
         in_specs=in_specs,
         out_specs=out_specs,
