@@ -291,10 +291,11 @@ def phi_recursive_vmem_bytes(meta: AltoMeta, mode: int, rank: int,
 
     Word blocks and the staging of all N coordinates (the target mode
     indexes Temp and B), the double-buffered ``(T, R)`` partition Temp,
-    resident B, and the Π tile or resident other factors. Nothing here
-    is tunable (Φ runs full rank, at the fixed block), so this footprint is
-    advisory — it is reported in the plan and used by the per-shard
-    budget checks.
+    resident B, the Π tile or resident other factors, and the kernel's
+    three ``(block_m, R)`` scratch tiles (Φ rows, gathered B rows, value
+    splats). Nothing here is tunable (Φ runs full rank, at the fixed
+    block), so this footprint is advisory — it is reported in the plan
+    and used by the per-shard budget checks.
     """
     T = meta.temp_rows[mode]
     if pre_pi:
@@ -304,7 +305,8 @@ def phi_recursive_vmem_bytes(meta: AltoMeta, mode: int, rank: int,
     return (_stream_bytes(meta, MIN_BLOCK_M, meta.enc.ndim)
             + 2 * _tile_bytes(T, rank, dtype_bytes)
             + _tile_bytes(meta.dims[mode], rank, dtype_bytes)
-            + operands)
+            + operands
+            + 3 * _tile_bytes(MIN_BLOCK_M, rank, dtype_bytes))
 
 
 def rank_tiles(rank: int) -> list[int]:

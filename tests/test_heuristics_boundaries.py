@@ -196,7 +196,8 @@ class TestPhiVmemFootprint:
         want = (_words(meta, bm, meta.enc.ndim)  # words + all-mode staging
                 + 2 * _tile(T, R)               # partition Temp output
                 + _tile(meta.dims[mode], R)     # RESIDENT full-rank B
-                + _others(meta, mode, R))       # resident other factors
+                + _others(meta, mode, R)        # resident other factors
+                + 3 * _tile(bm, R))             # Φ, B-row, value scratch
         got = plan_mod.phi_recursive_vmem_bytes(meta, mode, R, db)
         assert got == want
 

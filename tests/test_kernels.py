@@ -10,6 +10,7 @@ from repro.kernels.delinearize import delinearize_pallas
 from repro.kernels.mttkrp import mttkrp_partials_pallas
 from repro.kernels.cpapr_phi import phi_partials_pallas
 from repro.sparse import synthetic
+from repro.sparse.tensor import SparseTensor
 
 
 def _setup(dims, nnz, L, R, seed=0, dtype=jnp.float32, count=True):
@@ -101,3 +102,77 @@ def test_partials_match_ref_directly():
                                  factors)
     scale = float(jnp.max(jnp.abs(pr))) + 1e-9
     assert float(jnp.max(jnp.abs(pk - pr))) / scale < 1e-5
+
+
+def _phi_partials_pair(at, factors, mode, B, pre, eps=1e-10):
+    """Recursive Φ partials (before the pull reduction) from the kernel
+    and from the oracle, under ALTO-PRE or ALTO-OTF."""
+    enc, T = at.meta.enc, at.meta.temp_rows[mode]
+    if pre:
+        kw = dict(pi=core_mttkrp.krp_rows(at.coords(), factors, mode))
+    else:
+        kw = dict(factors=factors)
+    args = (enc, mode, T, eps, at.words, at.values, at.part_start, B)
+    return phi_partials_pallas(*args, **kw), ref.ref_phi_partials(*args, **kw)
+
+
+def _rel_err(got, want) -> float:
+    scale = float(jnp.max(jnp.abs(want))) + 1e-9
+    return float(jnp.max(jnp.abs(got - want))) / scale
+
+
+@pytest.mark.parametrize("pre", [False, True], ids=["otf", "pre"])
+def test_phi_partials_match_ref_directly(pre):
+    x, at, factors = _setup((40, 32, 24), 2000, 4, 16)
+    for mode in range(3):
+        B = jnp.abs(factors[mode]) + 0.1
+        got, want = _phi_partials_pair(at, factors, mode, B, pre)
+        assert _rel_err(got, want) < 1e-5
+
+
+def _few_rows_tensor(dims, mode, nnz, seed=0):
+    """Distinct nonzeros whose ``mode`` coordinate takes three values, so
+    a block's elements hit few Temp rows, mostly the previous one's."""
+    rng = np.random.default_rng(seed)
+    coords = np.stack([rng.integers(0, I, 3 * nnz) for I in dims], 1)
+    coords[:, mode] = rng.choice([1, dims[mode] // 2, dims[mode] - 1],
+                                 3 * nnz)
+    coords = np.unique(coords, axis=0)
+    coords = coords[rng.permutation(len(coords))[:nnz]]
+    values = rng.integers(1, 10, len(coords)).astype(np.float32)
+    return SparseTensor(dims, coords, values)
+
+
+@pytest.mark.parametrize("pre", [False, True], ids=["otf", "pre"])
+@pytest.mark.parametrize("mode", [0, 1, 2, 3])
+def test_phi_partials_repeated_rows(mode, pre):
+    """Short 4-mode shape, consecutive elements on one Temp row: the
+    scatter's read-modify-writes of a row follow one another."""
+    dims, L, R = (24, 8, 40, 56), 2, 16
+    at = alto.build(_few_rows_tensor(dims, mode, 2500), n_partitions=L)
+    rows = np.asarray(at.coords())[:, mode].reshape(L, -1)
+    assert (rows[:, 1:] == rows[:, :-1]).mean() > 0.5
+    rng = np.random.default_rng(1)
+    factors = [jnp.asarray(np.abs(rng.standard_normal((I, R)))
+                           .astype(np.float32) + 0.05) for I in dims]
+    B = factors[mode] + 0.1
+    got, want = _phi_partials_pair(at, factors, mode, B, pre)
+    assert _rel_err(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("pre", [False, True], ids=["otf", "pre"])
+def test_phi_partials_eps_floor(pre):
+    """Rows of B near zero put <B row, krp> under ε: those elements take
+    v / ε, the others v / <B row, krp>, both as the oracle does."""
+    x, at, factors = _setup((40, 32, 24), 2000, 4, 16)
+    mode, eps = 0, 0.5
+    B = np.abs(np.asarray(factors[mode])) + 0.1
+    B[::2] *= 1e-3                         # even rows fall under ε
+    B = jnp.asarray(B)
+    coords = at.coords()
+    dots = jnp.sum(B[coords[:, mode]]
+                   * core_mttkrp.krp_rows(coords, factors, mode), axis=-1)
+    floored = float(jnp.mean(dots < eps))
+    assert 0.1 < floored < 0.9
+    got, want = _phi_partials_pair(at, factors, mode, B, pre, eps=eps)
+    assert _rel_err(got, want) < 1e-5

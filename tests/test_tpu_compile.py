@@ -18,6 +18,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import alto, heuristics, plan as plan_mod
@@ -143,17 +144,61 @@ def test_carry_phi_compiles(one_chip, pre):
                       s["rows"], s["words"], s["values"], B, factors)
 
 
-def test_recursive_phi_compiles(one_chip):
+def _compile_phi_within_model(monkeypatch, meta, mode, pre, args):
+    """Compile the recursive Φ kernel with Mosaic's scoped-VMEM limit set
+    to `plan.phi_recursive_vmem_bytes`, so scratch the model leaves out
+    fails the compile."""
+    budget = plan_mod.phi_recursive_vmem_bytes(meta, mode, RANK,
+                                               pre_pi=pre)
+    monkeypatch.setattr(cpapr_phi, "compiler_params",
+                        lambda *sem: pltpu.CompilerParams(
+                            dimension_semantics=sem,
+                            vmem_limit_bytes=budget))
+    fn = functools.partial(cpapr_phi.phi_partials_pallas, meta.enc, mode,
+                           meta.temp_rows[mode], 1e-10, interpret=False)
+    if pre:
+        _compile_fits(lambda w, v, ps, b, p: fn(w, v, ps, b, pi=p), *args)
+    else:
+        _compile_fits(lambda w, v, ps, b, f: fn(w, v, ps, b, factors=f),
+                      *args)
+
+
+def test_recursive_phi_compiles(one_chip, monkeypatch):
     meta = _meta(reuse=100.0)
     mode = 0
     mp = _mode_plan(meta, mode, Traversal.RECURSIVE)
     assert mp.block_m == plan_mod.MIN_BLOCK_M      # the kernel's fixed block
     s, factors, B, sds = _shapes(one_chip, meta, mode=mode, B=True)
-    fn = functools.partial(cpapr_phi.phi_partials_pallas, meta.enc, mode,
-                           meta.temp_rows[mode], 1e-10, interpret=False)
-    _compile_fits(lambda w, v, ps, b, f: fn(w, v, ps, b, factors=f),
-                  s["words"], s["values"],
-                  sds((meta.n_partitions, 3), jnp.int32), B, factors)
+    _compile_phi_within_model(
+        monkeypatch, meta, mode, False,
+        (s["words"], s["values"], sds((meta.n_partitions, 3), jnp.int32), B,
+         factors))
+
+
+UBER = (183, 24, 1_140, 1_717)          # FROSTT uber
+UBER_NNZ = 3_309_496                    # 3,309,490 padded to 8 partitions
+
+
+@pytest.mark.parametrize("pre", [False, True], ids=["otf", "pre"])
+@pytest.mark.parametrize("mode", [0, 1, 2, 3])
+def test_recursive_phi_compiles_at_uber_shape(one_chip, monkeypatch, mode,
+                                              pre):
+    """CP-APR's benchmark tensor: four modes, two index words, every
+    partition spanning every mode."""
+    meta = alto.AltoMeta(enc=make_encoding(UBER), nnz=UBER_NNZ,
+                         n_partitions=8, temp_rows=UBER,
+                         fiber_reuse=(8.0,) * 4)
+    assert meta.enc.n_words == 2
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    operand = (sds((UBER_NNZ, RANK), jnp.float32) if pre
+               else [sds((I, RANK), jnp.float32) for I in UBER])
+    _compile_phi_within_model(
+        monkeypatch, meta, mode, pre,
+        (sds((UBER_NNZ, 2), jnp.uint32), sds((UBER_NNZ,), jnp.float32),
+         sds((8, 4), jnp.int32), sds((UBER[mode], RANK), jnp.float32),
+         operand))
 
 
 def test_plan_tilings_are_chip_legal():
