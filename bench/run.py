@@ -4,12 +4,28 @@
 
 The cell is an entry of ``workloads`` in the checkout's ``BENCHMARK.json``.
 It names a configuration (a file of sizes under ``bench/configs/``) and a
-traffic mix (``bench/traffic/<traffic>.json``), and the mix names its
-driver (``bench/drivers/<driver>.py``). Each metric is read by its own
-file ``bench/metrics/<metric>.py``. A cell's own file,
+traffic mix (``bench/traffic/<traffic>.json``). The mix names its driver
+(``bench/drivers/<driver>.py``) and its coordinate law
+(``bench/coords/<distribution>.py``, read by `gen`). Each metric is read
+by its own file ``bench/metrics/<metric>.py``. A cell's own file,
 ``bench/cells/<workload>.json``, gives the limit of each number that
-decides ``correct`` and its nominal step time. Nothing here names a cell:
-a cell, mix or metric is added by adding files.
+decides ``correct`` and its nominal step time. Nothing here names a
+cell, configuration, law, mix or metric; each is added by adding files:
+
+* a configuration: ``bench/configs/<name>.json`` with its published
+  ``dims``, ``nnz``, ``rank``, ``values`` and ``dtype``, and a
+  ``cpu_cut`` (``{"dims": [...], "nnz": n}``, one dim per mode, none
+  above the published one nor below a cube edge its mixes ask for) that
+  the CPU tests run in its place; plus its ``configs`` entry;
+* a coordinate law: ``bench/coords/<distribution>.py`` with
+  ``coords(key, dims, nnz, spec)``;
+* a traffic mix: ``bench/traffic/<traffic>.json``;
+* a cell: ``bench/cells/<workload>.json`` and its ``workloads`` entry;
+* a metric: ``bench/metrics/<metric>.py`` with ``read(run)``, and its
+  entry.
+
+The only entries already there that change are the ``workloads`` lists
+of the metrics the new cell reports.
 
 One run:
 
@@ -43,7 +59,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -52,35 +67,16 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 import traceback  # noqa: E402
 
-BENCH = pathlib.Path(__file__).resolve().parent
-CHECKOUT = BENCH.parent
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+from bench.files import (BENCH, CHECKOUT, BenchError,  # noqa: E402
+                         load_json, load_module)
+
 SPEC = CHECKOUT / "BENCHMARK.json"
 COMPILE_CACHE = CHECKOUT / ".cache" / "jax"
 
 
-class BenchError(Exception):
-    """A run that cannot give a result: it exits non-zero, unprinted."""
-
-
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
-
-
-def load_module(path: pathlib.Path):
-    """A benchmark file loaded by path (metric names carry dots)."""
-    if not path.is_file():
-        raise BenchError(f"missing {path.relative_to(CHECKOUT)}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def load_json(path: pathlib.Path) -> dict:
-    if not path.is_file():
-        raise BenchError(f"missing {path.relative_to(CHECKOUT)}")
-    return json.loads(path.read_text())
 
 
 @dataclasses.dataclass
@@ -373,5 +369,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    sys.path.insert(0, str(CHECKOUT))
     sys.exit(main())

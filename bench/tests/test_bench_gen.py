@@ -1,6 +1,7 @@
-"""The device generator: deterministic in the seed, the published nnz, and
+"""The device generator: deterministic in the seed, the published nnz,
 clustered coordinates distinct within each cube with fiber reuse above
-the plan's threshold of 4."""
+the plan's threshold of 4, and each coordinate law found by name."""
+import hashlib
 import json
 import pathlib
 
@@ -9,8 +10,11 @@ import numpy as np
 import pytest
 
 from bench import gen, reference
+from bench.files import BenchError
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = {c["name"]: c["file"] for c in SPEC["configs"]}
 CLUSTERED = json.loads(
     (ROOT / "bench/traffic/apr.clustered.json").read_text())["coords"]
 UNIFORM = {"distribution": "uniform"}
@@ -47,11 +51,46 @@ def test_seeds_past_32_bits_do_not_alias():
     assert not np.array_equal(np.asarray(lo), np.asarray(hi))
 
 
-@pytest.mark.parametrize("name", ["uber"])
+# sha256 (first 32 hex digits) of a tensor's int32 coordinates then its
+# float32 values, little-endian, from the generator as it stood before the
+# laws moved to bench/coords/: every seed gives the same tensor, bit for bit.
+PINNED = {
+    ("uniform", 3, 7): "4535016ca4c6a940e71ecad74cb138d0",
+    ("uniform", 3, 2 ** 32 + 2 ** 31 + 19): "9b7b2ff01935e431d29feee11f7d6e64",
+    ("uniform", 4, 7): "c65e449cbc123c487dd98623e9aa694e",
+    ("uniform", 4, 2 ** 32 + 2 ** 31 + 19): "eee6e78e5207e29cb41ddf2d978de433",
+    ("clustered", 3, 7): "6f00968711146b5c21f4667c2350d3bc",
+    ("clustered", 3, 2 ** 32 + 2 ** 31 + 19):
+        "946809a34187e3004200aa5df8844bcd",
+    ("clustered", 4, 7): "4b12057400b23778bae4d87bf22e34b3",
+    ("clustered", 4, 2 ** 32 + 2 ** 31 + 19):
+        "f28f6f8173c691a234320bf2658f4e7c",
+}
+PINNED_SHAPES = {3: ((40, 30, 50), 5000), 4: ((18, 24, 40, 30), 30_000)}
+
+
+@pytest.mark.parametrize("law,modes,seed", PINNED)
+def test_tensor_pinned_bit_for_bit(law, modes, seed):
+    coords = {"uniform": UNIFORM, "clustered": CLUSTERED}[law]
+    dims, nnz = PINNED_SHAPES[modes]
+    c, v = _np(gen.generate(dims, nnz, coords, GAUSS, seed))
+    h = hashlib.sha256()
+    h.update(np.ascontiguousarray(c, "<i4").tobytes())
+    h.update(np.ascontiguousarray(v, "<f4").tobytes())
+    assert h.hexdigest()[:32] == PINNED[law, modes, seed]
+
+
+def test_unknown_distribution_names_the_missing_file():
+    with pytest.raises(BenchError, match="bench/coords/no_such_law.py"):
+        gen.generate((8, 8, 8), 100, {"distribution": "no_such_law"},
+                     GAUSS, 1)
+
+
+@pytest.mark.parametrize("name", CONFIGS)
 @pytest.mark.parametrize("coords", [UNIFORM, CLUSTERED],
                          ids=["uniform", "clustered"])
 def test_published_nnz(name, coords):
-    cfg = json.loads((ROOT / f"bench/configs/{name}.json").read_text())
+    cfg = json.loads((ROOT / CONFIGS[name]).read_text())
     fn = gen._generator(tuple(cfg["dims"]), cfg["nnz"], gen._freeze(coords),
                         gen._freeze(cfg["values"]))
     c, v = jax.eval_shape(fn, gen.seed_key(0))

@@ -15,16 +15,16 @@ from bench import run as bench_run
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
-SMALL = {"uber": {"dims": [18, 24, 40, 30], "nnz": 30_000}}
 
 
-def small_spec(tmp_path) -> dict:
-    """BENCHMARK.json with every configuration cut to a CPU-sized tensor
-    (the traffic mixes, drivers and limits are the cells' own)."""
-    spec = json.loads(json.dumps(SPEC))
+def small_spec(tmp_path, spec=SPEC) -> dict:
+    """``spec`` with every configuration cut to the CPU-sized tensor of
+    its own file's ``cpu_cut`` (the traffic mixes, drivers and limits are
+    the cells' own)."""
+    spec = json.loads(json.dumps(spec))
     for c in spec["configs"]:
         body = json.loads((ROOT / c["file"]).read_text())
-        body.update(SMALL[c["name"]])
+        body.update(body["cpu_cut"])
         path = tmp_path / f"{c['name']}.json"
         path.write_text(json.dumps(body))
         c["file"] = str(path)
@@ -92,3 +92,33 @@ def test_traced_run_reports_per_layer(tmp_path):
     assert out["device"]["window_s"] > 0
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(out)[-1] == "checks"
+
+
+def test_small_spec_takes_a_configuration_added_by_a_file(tmp_path):
+    """A second, five-mode configuration in its own file with its own
+    cut: ``small_spec`` cuts it by that file alone, and every existing
+    cell resolves as it did without it."""
+    five = {"name": "five", "dims": [1605, 4198, 1631, 4209, 868131],
+            "nnz": 1698825, "rank": 16, "dtype": "float32",
+            "values": {"kind": "counts", "low": 1, "high": 9},
+            "cpu_cut": {"dims": [16, 20, 16, 24, 40], "nnz": 20000}}
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "five.json").write_text(json.dumps(five))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": "five", "source": "a test",
+                            "file": str(src / "five.json"), "reduced": [],
+                            "why": "a test"})
+    (tmp_path / "one").mkdir()
+    (tmp_path / "two").mkdir()
+    alone = small_spec(tmp_path / "one")
+    both = small_spec(tmp_path / "two", spec)
+    cut = next(c for c in both["configs"] if c["name"] == "five")
+    body = json.loads(pathlib.Path(cut["file"]).read_text())
+    assert body["dims"] == five["cpu_cut"]["dims"]
+    assert body["nnz"] == five["cpu_cut"]["nnz"]
+    for cell in CELLS:
+        assert bench_run.resolve(both, cell) == bench_run.resolve(alone, cell)
+        config = bench_run.resolve(alone, cell).config
+        assert (config["dims"], config["nnz"]) == (
+            config["cpu_cut"]["dims"], config["cpu_cut"]["nnz"])

@@ -71,3 +71,19 @@ def test_config_files(config):
     assert body["name"] == config["name"] and config["reduced"] == []
     assert len(body["dims"]) >= 3 and body["nnz"] > 0
     assert body["dtype"] == "float32" and "assumed" in body
+
+
+@pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
+def test_config_cpu_cut(config):
+    """The CPU tests' tensor: as many modes as the configuration, no dim
+    or nnz above the published one, and room in every mode for each cube
+    edge the configuration's cells' mixes ask for."""
+    body = json.loads((ROOT / config["file"]).read_text())
+    cut = body["cpu_cut"]
+    assert len(cut["dims"]) == len(body["dims"])
+    assert all(0 < c <= d for c, d in zip(cut["dims"], body["dims"]))
+    assert 0 < cut["nnz"] <= body["nnz"]
+    for w in SPEC["workloads"]:
+        if w["config"] == config["name"]:
+            mix = bench_run.resolve(SPEC, w["name"]).traffic["coords"]
+            assert min(cut["dims"]) >= mix.get("edge", 1), w["name"]
